@@ -60,6 +60,8 @@ def main() -> int:
     ap.add_argument("--bench-dir", default=".",
                     help="where BENCH_*.json files are written")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     want = set(args.tables.split(","))
     t0 = time.time()
 

@@ -39,11 +39,12 @@ from __future__ import annotations
 
 import functools
 import heapq
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from .. import shardlib as sl
 from ..kernels.edge_relax.ops import relax_bucketed
@@ -81,6 +82,16 @@ def _plan_to_device(plan: SweepPlan):
             jnp.asarray(plan.row_valid), jnp.asarray(plan.level_mask))
 
 
+class _Plans(NamedTuple):
+    """The three device-resident sweep plans.  Every jitted query takes
+    them as an *operand*, never as a closed-over constant: a constant
+    would be embedded in the compiled program, whose size (and compile
+    time) would then grow with the index."""
+    f: tuple   # forward search (§5.1)
+    b: tuple   # backward search (§5.3)
+    c: tuple   # core edges, read only by SSSP reconstruction (§6)
+
+
 def _dense_core_adjacency(ix: HoDIndex) -> np.ndarray:
     """Dense [C, C] core adjacency from the raw CSR (scatter, no Python
     loop) — only the paper-faithful Bellman core mode reads it."""
@@ -94,6 +105,19 @@ def _dense_core_adjacency(ix: HoDIndex) -> np.ndarray:
             np.minimum.at(adj, (cu, ix.core_dst),
                           ix.core_w.astype(np.float32))
     return adj
+
+
+def _per_batch_shard(fn, batched: Tuple[bool, ...], *args):
+    """``fn(*args)`` run once per shard of the logical ``"batch"`` axis
+    under an active mesh (a plain call otherwise).  XLA cannot partition
+    a Pallas TPU kernel on its own, so the sweep's kernels see their
+    per-device rows through ``shard_map``.  ``batched[i]`` says whether
+    ``args[i]`` leads with the batch axis (else it is replicated); the
+    result does."""
+    b = sl.logical_to_spec("batch")
+    return sl.maybe_shard_map(
+        fn, in_specs=tuple(b if x else P() for x in batched),
+        out_specs=b)(*args)
 
 
 def _minplus_blocked(a: jnp.ndarray, b: jnp.ndarray,
@@ -147,9 +171,9 @@ class QueryEngine:
         self._init_engine(index, core_mode, use_pallas, eps, interpret)
 
         index.ensure_plans(k_cap)   # no-op for pack_index/v2+-load indexes
-        self._plan_f = _plan_to_device(index.plan_f)
-        self._plan_b = _plan_to_device(index.plan_b)
-        self._plan_c = _plan_to_device(index.plan_core)
+        self._plans = _Plans(_plan_to_device(index.plan_f),
+                             _plan_to_device(index.plan_b),
+                             _plan_to_device(index.plan_core))
 
         self._ssd_jit = jax.jit(functools.partial(
             self._ssd_impl, core_mode=self.core_mode), static_argnames=())
@@ -180,8 +204,6 @@ class QueryEngine:
                           if interpret is None else interpret)
         self.eps = float(eps)
 
-        self._perm = jnp.asarray(index.perm)
-        self._closure = jnp.asarray(index.core_closure)
         # Meet-node metadata (DESIGN.md §7): the graph level behind each
         # real plan level, in scan order — derived from the resident
         # chunk arrays, so the store-backed engine gets it without
@@ -189,10 +211,16 @@ class QueryEngine:
         # provably-inert levels (everything below the query endpoints).
         self._level_ids_f = plan_level_ids(index, forward=True)
         self._level_ids_b = plan_level_ids(index, forward=False)
-        # Dense core adjacency is only materialized for the mode that
-        # scans it; closure/dijkstra engines skip the [C, C] build.
-        self._core_adj = (jnp.asarray(_dense_core_adjacency(index))
-                          if core_mode == "bellman" else None)
+        # The [C, C] matrix the jitted core search reads — the closure,
+        # or the dense adjacency the bellman mode iterates — passed to
+        # every jitted query as an operand.  The dijkstra mode searches
+        # the core CSR on the host and needs neither.
+        if core_mode == "closure":
+            self._core = jnp.asarray(index.core_closure)
+        elif core_mode == "bellman":
+            self._core = jnp.asarray(_dense_core_adjacency(index))
+        else:
+            self._core = None
 
     # ------------------------------------------------------- plan executor
     def _run_plan(self, state: jnp.ndarray, plan, level_body,
@@ -255,9 +283,10 @@ class QueryEngine:
         """
         del assoc
         cur = dist[:, dst]
-        new = relax_bucketed(dist, src_idx, w, cur, row_valid=valid,
-                             use_pallas=self.use_pallas,
-                             interpret=self.interpret)
+        relax = functools.partial(relax_bucketed, use_pallas=self.use_pallas,
+                                  interpret=self.interpret)
+        new = _per_batch_shard(relax, (True, False, False, True, False),
+                               dist, src_idx, w, cur, valid)
         return dist.at[:, dst].min(new)
 
     def _relax_level_rev(self, dlab, dst, src_idx, w, assoc, valid):
@@ -313,7 +342,10 @@ class QueryEngine:
         return body
 
     # ------------------------------------------------------------------ SSD
-    def _core_update(self, dist: jnp.ndarray, core_mode: str) -> jnp.ndarray:
+    def _core_update(self, dist: jnp.ndarray, core: jnp.ndarray,
+                     core_mode: str) -> jnp.ndarray:
+        """Core search (§5.2) over ``core``: the dense adjacency in
+        bellman mode, the all-pairs closure in closure mode."""
         ix = self.index
         c = ix.n_core
         if c == 0:
@@ -330,7 +362,7 @@ class QueryEngine:
 
             def body(state):
                 d, _, it = state
-                nd = jnp.minimum(d, _minplus_blocked(d, self._core_adj))
+                nd = jnp.minimum(d, _minplus_blocked(d, core))
                 return nd, jnp.any(nd < d), it + 1
 
             dc, _, _ = jax.lax.while_loop(
@@ -338,9 +370,11 @@ class QueryEngine:
         else:  # closure
             if self.use_pallas:
                 from ..kernels.tropical_matmul.ops import minplus
-                dc = minplus(dc, self._closure, interpret=self.interpret)
+                dc = _per_batch_shard(
+                    functools.partial(minplus, interpret=self.interpret),
+                    (True, False), dc, core)
             else:
-                dc = _minplus_blocked(dc, self._closure)
+                dc = _minplus_blocked(dc, core)
         return jax.lax.dynamic_update_slice_in_dim(dist, dc, lo, axis=1)
 
     def _init_state(self, nodes_perm: jnp.ndarray) -> jnp.ndarray:
@@ -353,26 +387,25 @@ class QueryEngine:
         state = state.at[jnp.arange(s), nodes_perm].set(0.0)
         return sl.shard(state, "batch", None)
 
-    def _forward_core(self, sources_perm: jnp.ndarray, core_mode: str,
-                      level_body=None) -> jnp.ndarray:
+    def _forward_core(self, plans: _Plans, core, sources_perm: jnp.ndarray,
+                      core_mode: str, level_body=None) -> jnp.ndarray:
         """Forward search (§5.1) + core search (§5.2): the shared first
         two phases of SSD, P2P, and threshold queries."""
         dist = self._init_state(sources_perm)
-        dist = self._run_plan(dist, self._plan_f,
-                              level_body or self._relax_level)
+        dist = self._run_plan(dist, plans.f, level_body or self._relax_level)
         if core_mode != "dijkstra":
-            dist = self._core_update(dist, core_mode)
+            dist = self._core_update(dist, core, core_mode)
         return dist
 
-    def _ssd_impl(self, sources_perm: jnp.ndarray,
+    def _ssd_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
                   core_mode: str) -> jnp.ndarray:
-        dist = self._forward_core(sources_perm, core_mode)
-        dist = self._run_plan(dist, self._plan_b,       # backward search(§5.3)
+        dist = self._forward_core(plans, core, sources_perm, core_mode)
+        dist = self._run_plan(dist, plans.b,            # backward search(§5.3)
                               self._relax_level)
         return dist
 
-    def _p2p_impl(self, sources_perm: jnp.ndarray, targets_perm: jnp.ndarray,
-                  core_mode: str) -> jnp.ndarray:
+    def _p2p_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
+                  targets_perm: jnp.ndarray, core_mode: str) -> jnp.ndarray:
         """Meet-in-the-middle P2P distances (DESIGN.md §7).
 
         Forward labels of ``s`` (forward sweep + core search — exactly
@@ -382,27 +415,29 @@ class QueryEngine:
         property (Theorem 1) every shortest path ascends, optionally
         crosses the core — folded into ``fwd`` by the core search — and
         descends, so some node ``m`` on it carries both labels."""
-        fwd = self._forward_core(sources_perm, core_mode)
+        fwd = self._forward_core(plans, core, sources_perm, core_mode)
         bwd = self._init_state(targets_perm)
-        bwd = self._run_plan(bwd, self._plan_b, self._relax_level_rev,
+        bwd = self._run_plan(bwd, plans.b, self._relax_level_rev,
                              reverse=True)
         return jnp.min(fwd + bwd, axis=1)
 
-    def _within_impl(self, sources_perm: jnp.ndarray, d: jnp.ndarray,
-                     core_mode: str) -> jnp.ndarray:
+    def _within_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
+                     d: jnp.ndarray, core_mode: str) -> jnp.ndarray:
         """Distance-threshold SSD (DESIGN.md §7): the full sweep pipeline
         with the ``<= d`` mask applied inside every scan body, so labels
         past the threshold die where they arise instead of being
         filtered at the end — the masked levels are what the streaming
         engine skips reading entirely."""
         body = self._relax_level_thresh(d)
-        dist = self._forward_core(sources_perm, core_mode, level_body=body)
+        dist = self._forward_core(plans, core, sources_perm, core_mode,
+                                  level_body=body)
         dist = jnp.where(dist <= d, dist, INF)          # mask core output
-        return self._run_plan(dist, self._plan_b, body)
+        return self._run_plan(dist, plans.b, body)
 
-    def _sssp_impl(self, sources_perm: jnp.ndarray, core_mode: str):
+    def _sssp_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
+                   core_mode: str):
         ix = self.index
-        dist = self._ssd_impl(sources_perm, core_mode)
+        dist = self._ssd_impl(plans, core, sources_perm, core_mode)
         s = sources_perm.shape[0]
         pred = jnp.full((s, ix.n_pad), -1, jnp.int32)
         recon = self._recon_level_body(dist)
@@ -410,7 +445,7 @@ class QueryEngine:
         # fixed `dist`, so the plan order commutes; the store-backed
         # engine exploits this by walking plans in reverse (cache
         # affinity with the distance pass) and stays bit-identical.
-        for plan in (self._plan_f, self._plan_c, self._plan_b):
+        for plan in (plans.f, plans.c, plans.b):
             pred = self._run_plan(pred, plan, recon)
         return dist, pred
 
@@ -422,7 +457,8 @@ class QueryEngine:
         if self.core_mode == "dijkstra":
             dist = self._dijkstra_path(src_perm)
         else:
-            dist = self._ssd_jit(jnp.asarray(src_perm))
+            dist = self._ssd_jit(self._plans, self._core,
+                                 jnp.asarray(src_perm))
         return np.asarray(dist)[:, self.index.perm]
 
     def sssp(self, sources: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -438,10 +474,10 @@ class QueryEngine:
             pred = jnp.full((dist.shape[0], self.index.n_pad), -1,
                             jnp.int32)
             recon = self._recon_level_body(dist)
-            for plan in (self._plan_f, self._plan_c, self._plan_b):
+            for plan in (self._plans.f, self._plans.c, self._plans.b):
                 pred = self._run_plan(pred, plan, recon)
         else:
-            dist, pred = self._sssp_jit(src_perm)
+            dist, pred = self._sssp_jit(self._plans, self._core, src_perm)
         dist = np.asarray(dist)[:, self.index.perm]
         pred = np.asarray(pred)[:, self.index.perm]
         return dist, pred
@@ -461,10 +497,11 @@ class QueryEngine:
         if self.core_mode == "dijkstra":
             fwd = self._dijkstra_forward_core(src_perm)
             bwd = self._init_state(jnp.asarray(tgt_perm))
-            bwd = self._run_plan(bwd, self._plan_b, self._relax_level_rev,
+            bwd = self._run_plan(bwd, self._plans.b, self._relax_level_rev,
                                  reverse=True)
             return np.asarray(jnp.min(jnp.asarray(fwd) + bwd, axis=1))
-        return np.asarray(self._p2p_jit(jnp.asarray(src_perm),
+        return np.asarray(self._p2p_jit(self._plans, self._core,
+                                        jnp.asarray(src_perm),
                                         jnp.asarray(tgt_perm)))
 
     def ssd_within(self, sources: np.ndarray, d: float) -> np.ndarray:
@@ -478,12 +515,13 @@ class QueryEngine:
         if self.core_mode == "dijkstra":
             body = self._relax_level_thresh(jnp.float32(d))
             dist = self._init_state(jnp.asarray(src_perm))
-            dist = self._run_plan(dist, self._plan_f, body)
+            dist = self._run_plan(dist, self._plans.f, body)
             dist = self._core_dijkstra_host(np.array(dist))
             dist = jnp.where(jnp.asarray(dist) <= d, jnp.asarray(dist), INF)
-            dist = self._run_plan(dist, self._plan_b, body)
+            dist = self._run_plan(dist, self._plans.b, body)
         else:
-            dist = self._within_jit(jnp.asarray(src_perm), jnp.float32(d))
+            dist = self._within_jit(self._plans, self._core,
+                                    jnp.asarray(src_perm), jnp.float32(d))
         return np.asarray(dist)[:, self.index.perm]
 
     def knn(self, sources: np.ndarray, k: int
@@ -549,7 +587,7 @@ class QueryEngine:
         """Forward plan sweep (JAX) -> host heap Dijkstra on G_c: the
         shared front half of the paper-faithful SSD and P2P pipelines."""
         dist = self._init_state(jnp.asarray(sources_perm))
-        dist = np.array(self._run_plan(dist, self._plan_f,
+        dist = np.array(self._run_plan(dist, self._plans.f,
                                        self._relax_level))  # writable copy
         return self._core_dijkstra_host(dist)
 
@@ -558,7 +596,7 @@ class QueryEngine:
         backward plan sweep (JAX): the literal §5 pipeline, used as a
         validation mode."""
         dist = self._dijkstra_forward_core(sources_perm)
-        return np.asarray(self._run_plan(jnp.asarray(dist), self._plan_b,
+        return np.asarray(self._run_plan(jnp.asarray(dist), self._plans.b,
                                          self._relax_level))
 
 

@@ -135,7 +135,7 @@ def main() -> None:
 
         from ..obs import Tracer, validate_chrome_trace
         tracer = Tracer()
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+        mesh = sl.make_mesh((len(jax.devices()),), ("data",))
         with sl.axis_rules(mesh, {"batch": "data"}):
             two, srv2 = _serve_leg(cfg, store_dir, budget, stream, 2,
                                    tracer=tracer)

@@ -17,8 +17,7 @@ by the level's max in-degree bucket).
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .._compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 INF = float("inf")
 
@@ -35,7 +34,7 @@ def _relax_kernel(gathered_ref, w_ref, cur_ref, mask_ref, o_ref):
 def relax_bucketed_pallas(gathered: jnp.ndarray, w: jnp.ndarray,
                           cur: jnp.ndarray, row_valid: jnp.ndarray, *,
                           bs: int = 8, bm: int = 128,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool) -> jnp.ndarray:
     """gathered: [S, M, K] (dist[:, src[m,k]]); w: [M, K]; cur: [S, M];
     row_valid: [M] bool — False rows pass ``cur`` through untouched.
 
@@ -67,7 +66,7 @@ def relax_bucketed_pallas(gathered: jnp.ndarray, w: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bs_, bm_), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((ss, mm), cur.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(gathered, w, cur, mask)

@@ -21,8 +21,8 @@ TRACE_COUNT = 0
 def relax_bucketed(dist: jnp.ndarray, src_idx: jnp.ndarray,
                    w: jnp.ndarray, cur: jnp.ndarray,
                    row_valid: Optional[jnp.ndarray] = None,
-                   use_pallas: bool = True,
-                   interpret: bool = True) -> jnp.ndarray:
+                   use_pallas: bool = True, *,
+                   interpret: bool) -> jnp.ndarray:
     """One plan level's relaxation over a bucketed in-edge layout.
 
     dist: [S, N] finalized distances; src_idx: [M, K] source node of each

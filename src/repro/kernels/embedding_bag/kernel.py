@@ -8,7 +8,7 @@ being re-materialized for the reduce.  Grid: (B/bb, D/bd) with K whole.
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from .._compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _bag_kernel(g_ref, m_ref, o_ref):
@@ -39,7 +39,7 @@ def bag_sum_pallas(gathered: jnp.ndarray, mask: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((bb_, bd_), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bbp, ddp), gathered.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(gathered, mask)

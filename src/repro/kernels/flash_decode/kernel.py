@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params
-
 NEG_INF = float("-inf")
 
 
@@ -77,7 +75,7 @@ def _decode_one(q, k, v, kv_len, *, bk: int, interpret: bool):
             jax.ShapeDtypeStruct((g, 1), jnp.float32),
             jax.ShapeDtypeStruct((g, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(kv_len, q, k, v)

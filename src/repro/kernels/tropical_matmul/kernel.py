@@ -6,14 +6,17 @@ VPU.  We tile exactly like a matmul — grid (M/bm, N/bn, K/bk), the K axis
 innermost and "arbitrary" so each (i, j) output tile accumulates a running
 elementwise min across K blocks held in VMEM.  Inside a block the K
 reduction is sub-chunked (KI=8) so the [bm, KI, bn] broadcast intermediate
-stays ~0.5 MB, far under VMEM.
+stays ~0.5 MB, far under VMEM.  The sub-chunks are unrolled with static
+slices: Mosaic cannot lower a dynamic lane-axis slice whose start it cannot
+prove 128-aligned, so a ``fori_loop`` over KI-wide chunks does not compile
+for the chip.
 """
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from .._compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 INF = float("inf")  # python literal: kernels must not capture traced consts
 KI = 8  # inner K sub-chunk: [bm, KI, bn] is the largest VMEM intermediate
@@ -26,19 +29,18 @@ def _minplus_kernel(a_ref, b_ref, o_ref, *, bk: int):
 
     a = a_ref[...]          # [bm, bk]
     b = b_ref[...]          # [bk, bn]
-
-    def body(i, acc):
-        a_sub = jax.lax.dynamic_slice_in_dim(a, i * KI, KI, axis=1)
-        b_sub = jax.lax.dynamic_slice_in_dim(b, i * KI, KI, axis=0)
-        cand = jnp.min(a_sub[:, :, None] + b_sub[None, :, :], axis=1)
-        return jnp.minimum(acc, cand)
-
-    o_ref[...] = jax.lax.fori_loop(0, bk // KI, body, o_ref[...])
+    acc = o_ref[...]
+    for lo in range(0, bk, KI):
+        a_sub = a[:, lo:lo + KI]
+        b_sub = b[lo:lo + KI, :]
+        acc = jnp.minimum(acc, jnp.min(a_sub[:, :, None] + b_sub[None, :, :],
+                                       axis=1))
+    o_ref[...] = acc
 
 
 def minplus_pallas(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
                    bn: int = 128, bk: int = 128,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool) -> jnp.ndarray:
     """min-plus matmul; operands padded with +inf to block multiples.
 
     +inf padding is absorbing for (min, +): padded lanes never win.
@@ -64,7 +66,7 @@ def minplus_pallas(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kq: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), a.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

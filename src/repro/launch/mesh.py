@@ -23,17 +23,19 @@ from typing import Dict
 
 import jax
 
+from ..shardlib import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """1×1 mesh over the single CPU device: same code path, world size 1."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_mesh((1, n), ("data", "model"))
 
 
 def _dp(mesh) -> tuple:

@@ -84,7 +84,8 @@ from ..obs.metrics import Histogram, MetricsRegistry
 from ..obs.trace import span_if
 
 __all__ = ["QueryResult", "ServerStats", "BatchIO", "ClassSLO",
-           "QueryServer", "server_from_config", "mixed_request_stream"]
+           "QueryServer", "server_from_config", "mixed_request_stream",
+           "build_served_index"]
 
 
 @dataclasses.dataclass
@@ -979,6 +980,15 @@ class QueryServer:
             self.engine.close()
 
 
+def build_served_index(g):
+    """The serve CLI's index: HoD build + pack of ``g``, eliminating
+    until the core holds 512 nodes or 32K edges.  Returns ``(index,
+    build result)``."""
+    res = build_hod_fast(g, BuildConfig(max_core_nodes=512,
+                                        max_core_edges=1 << 15))
+    return pack_index(g, res, chunk=2048), res
+
+
 # ----------------------------------------------------------- config plumbing
 def server_from_config(cfg: Config, *, engine=None,
                        store_path: Optional[str] = None,
@@ -1260,6 +1270,8 @@ def load_serve_config(args: argparse.Namespace) -> Config:
 def main() -> None:
     ap = build_arg_parser()
     args = ap.parse_args()
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     try:
         cfg = load_serve_config(args)
     except ConfigError as exc:
@@ -1294,9 +1306,7 @@ def main() -> None:
          else power_law_digraph(side * side, 4, weighted=True))
     print(f"graph: n={g.n} m={g.m}")
     t0 = time.perf_counter()
-    res = build_hod_fast(g, BuildConfig(max_core_nodes=512,
-                                        max_core_edges=1 << 15))
-    ix = pack_index(g, res, chunk=2048)
+    ix, res = build_served_index(g)
     print(f"index built in {time.perf_counter()-t0:.1f}s "
           f"({ix.n_levels} levels, core {ix.n_core}, "
           f"{res.stats.shortcuts_added} shortcuts)")
@@ -1364,7 +1374,7 @@ def main() -> None:
             import jax
 
             from .. import shardlib as sl
-            mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+            mesh = sl.make_mesh((len(jax.devices()),), ("data",))
             with sl.axis_rules(mesh, {"batch": "data"}):
                 results = drive()
             print(f"data-parallel over {len(jax.devices())} device(s)")

@@ -20,25 +20,28 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# ``shard_map`` graduated from jax.experimental (where its replication
-# checker is spelled ``check_rep``) to ``jax.shard_map`` (``check_vma``).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # pragma: no cover - exercised on older JAX only
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
-    "AxisRules", "axis_rules", "current_rules", "current_mesh",
+    "make_mesh", "AxisRules", "axis_rules", "current_rules", "current_mesh",
     "logical_to_spec", "shard", "sharding_for", "maybe_shard_map",
     "psum", "pmax", "pmin", "psum_scatter", "all_gather", "axis_size",
     "axis_index",
 ]
 
 _state = threading.local()
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    :func:`shard` constrains arrays with ``with_sharding_constraint``,
+    which accepts only ``Auto`` mesh axes; ``jax.make_mesh`` alone gives
+    ``Explicit`` ones.  ``devices`` defaults to ``jax.devices()``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 class AxisRules:
@@ -188,9 +191,7 @@ def axis_index(axes: Sequence[str]):
         return jnp.int32(0)
     idx = jnp.int32(0)
     for a in axes:
-        size = (jax.lax.axis_size(a) if hasattr(jax.lax, "axis_size")
-                else jax.lax.psum(1, a))
-        idx = idx * size + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -205,5 +206,5 @@ def maybe_shard_map(fn: Callable, in_specs, out_specs) -> Callable:
     mesh = current_mesh()
     if mesh is None:
         return fn
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **_SHARD_MAP_KW)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

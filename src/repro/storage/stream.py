@@ -93,7 +93,7 @@ class StreamingQueryEngine(QueryEngine):
         self._init_engine(store.resident, core_mode, use_pallas, eps,
                           interpret)
         self._core_jit = jax.jit(
-            lambda dist: self._core_update(dist, self.core_mode))
+            lambda dist, core: self._core_update(dist, core, self.core_mode))
         # Level steps: state (arg 0) is donated, so the sweep runs with
         # one live state buffer + one level slab.  assoc is an operand
         # of both steps (unused by relax) so they share a signature.
@@ -253,7 +253,7 @@ class StreamingQueryEngine(QueryEngine):
                 # uses (QueryEngine._core_dijkstra_host).
                 return jnp.asarray(
                     self._core_dijkstra_host(np.array(dist)))
-            return self._core_jit(dist)
+            return self._core_jit(dist, self._core)
 
     def _ssd_stream(self, sources_perm: np.ndarray,
                     pin: bool = False) -> jnp.ndarray:

@@ -208,13 +208,11 @@ def test_shard_worker_crc_error_raises_in_query_thread(packed, tmp_path):
 
 # ------------------------------------------------------------- shardlib
 def test_pmin_identity_without_axes_and_under_1_device_mesh():
-    import jax
-
     from jax.sharding import PartitionSpec as P
 
     x = np.array([3.0, 1.0, 2.0], np.float32)
     np.testing.assert_array_equal(sl.pmin(x, ()), x)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = sl.make_mesh((1,), ("data",))
     with sl.axis_rules(mesh, {"batch": "data"}):
         out = sl.maybe_shard_map(
             lambda v: sl.pmin(v, ("data",)),

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 
+import repro.shardlib as sl
 from repro.launch.hlo_analysis import analyze
 
 
@@ -41,7 +42,7 @@ def test_nested_loops_multiply():
 
 def test_collectives_in_loops_counted():
     m = 128
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = sl.make_mesh((1,), ("x",))
 
     def f(x):
         def body(c, _):
@@ -49,11 +50,10 @@ def test_collectives_in_loops_counted():
         out, _ = jax.lax.scan(body, x, None, length=7)
         return out
 
-    from repro.shardlib import _SHARD_MAP_KW, _shard_map
     with mesh:
-        g = _shard_map(f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
-                       out_specs=jax.sharding.PartitionSpec(),
-                       **_SHARD_MAP_KW)
+        g = jax.shard_map(f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
+                          out_specs=jax.sharding.PartitionSpec(),
+                          check_vma=False)
         c = jax.jit(g).lower(
             jax.ShapeDtypeStruct((m, m), jnp.float32)).compile()
     r = analyze(c.as_text())

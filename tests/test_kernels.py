@@ -18,7 +18,7 @@ def test_tropical_matmul(m, k, n, dtype):
     b = jnp.asarray(RNG.uniform(0, 10, (k, n)), dtype)
     # inject +inf (unreachable) entries — absorbing element
     a = a.at[0, 0].set(jnp.inf)
-    out = minplus(a, b)
+    out = minplus(a, b, interpret=True)
     ref = minplus_ref(a, b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
 
@@ -35,8 +35,9 @@ def test_edge_relax(s, n, m, k):
     if k > 1:  # padding lanes
         w = w.at[:, -1].set(jnp.inf)
     cur = jnp.asarray(RNG.uniform(0, 20, (s, m)), jnp.float32)
-    a = relax_bucketed(dist, src, w, cur, use_pallas=True)
-    b = relax_bucketed(dist, src, w, cur, use_pallas=False)
+    a = relax_bucketed(dist, src, w, cur, use_pallas=True, interpret=True)
+    b = relax_bucketed(dist, src, w, cur, use_pallas=False,
+                       interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 
 
@@ -52,8 +53,10 @@ def test_edge_relax_row_validity_mask(s, n, m, k):
     # row 0 is masked AND would win (zero weights): the mask must suppress it
     w = w.at[0].set(0.0)
     valid = jnp.asarray(RNG.random(m) < 0.6).at[0].set(False)
-    a = relax_bucketed(dist, src, w, cur, row_valid=valid, use_pallas=True)
-    b = relax_bucketed(dist, src, w, cur, row_valid=valid, use_pallas=False)
+    a = relax_bucketed(dist, src, w, cur, row_valid=valid, use_pallas=True,
+                       interpret=True)
+    b = relax_bucketed(dist, src, w, cur, row_valid=valid, use_pallas=False,
+                       interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
     inval = ~np.asarray(valid)
     np.testing.assert_array_equal(np.asarray(a)[:, inval],

@@ -159,7 +159,7 @@ def test_sharded_batch_axis_matches_unsharded(engine):
 
     sources = np.array([0, 3, 5, 7], dtype=np.int32)
     plain = engine.ssd(sources)
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = sl.make_mesh((len(jax.devices()),), ("data",))
     eng2 = QueryEngine(engine.index)
     with sl.axis_rules(mesh, {"batch": "data"}):
         sharded = eng2.ssd(sources)
