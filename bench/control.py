@@ -1,0 +1,90 @@
+#!/usr/bin/env python3
+"""Readings behind the limits of ``correct``: the program's and the control's.
+
+    python3 bench/control.py --workload <cell> --seeds 1,2,3 --seconds <s>
+
+One set-up of the cell at its own size, then per seed a window of the
+cell's own traffic.  For the sample of answers a run would check it
+prints the comparison's numbers twice: the program's answers against
+the reference (the lower reading), and the answers of the
+configuration's control put in the program's place (the upper reading).
+The control is the reference with one guarantee the configuration
+states broken (``yardstick.reference.control_for``).  Not a benchmark
+run: it prints no result line.
+"""
+import argparse
+import dataclasses
+import json
+import math
+import sys
+
+
+def readings(cell, arcs, truth, answered, seed: int) -> dict:
+    """The comparison's numbers for the program's ``answered`` and for
+    the control's answers to the same sampled requests."""
+    from yardstick import check, reference
+
+    mode = cell.traffic["mode"]
+    k = int(cell.config["check"][f"{mode}_answers"])
+    picks = check.sample(answered, k, seed)
+    guarantees = cell.config["guarantees"]
+    far = 0.0 if "labels" in guarantees else max(
+        _farthest(mode, answered[i].request, truth) for i in picks)
+    ctl = reference.control_for(guarantees, arcs, far)
+    swapped = list(answered)
+    for i in picks:
+        swapped[i] = with_control(answered[i], mode, ctl)
+    return {"program": check.compare(mode, answered, truth, k,
+                                     seed).numbers,
+            "control": check.compare(mode, swapped, truth, k,
+                                     seed).numbers,
+            "farthest": far}
+
+
+def _farthest(mode: str, request, truth) -> float:
+    if mode == "p2p":
+        return truth.p2p(*request)
+    return max(d for d in truth.ssd(request) if math.isfinite(d))
+
+
+def with_control(a, mode: str, ctl):
+    """``a`` with the control's answer to the same request in place of
+    the program's."""
+    answer = ctl.p2p(*a.request) if mode == "p2p" else ctl.ssd(a.request)
+    return dataclasses.replace(a, answer=answer)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", required=True,
+                    help="comma-separated seeds, one window each")
+    ap.add_argument("--seconds", type=float, required=True)
+    args = ap.parse_args(argv)
+
+    import jax
+    from yardstick import cell as cellmod
+    from yardstick import reference, spec, traffic
+
+    cell = spec.resolve(args.workload)
+    mix = traffic.validate(dict(cell.traffic))
+    try:
+        cellmod.require_chips(jax, cell.chips)
+    except cellmod.NoChip as exc:
+        print(f"control: {exc}", file=sys.stderr)
+        return 1
+    served = cellmod.setup(cell, mix["mode"], trace=False)
+    truth = reference.Reference(served.arcs)
+    for seed in (int(s) for s in args.seeds.split(",")):
+        res = cellmod.drive(served, mix, seed, args.seconds)
+        row = {"seed": seed, "requests": len(res.answered),
+               **readings(cell, served.arcs, truth, res.answered, seed)}
+        print("control: " + json.dumps(row), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    from yardstick.entry import prepare
+
+    prepare()
+    sys.exit(main())
