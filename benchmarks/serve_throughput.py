@@ -702,7 +702,7 @@ def latency_sweep(ix, sources: np.ndarray, *,
                 problems = validate_chrome_trace(doc)
                 assert not problems, problems[:5]
                 names = {e["name"] for e in doc["traceEvents"]}
-                need = {f"query.{mode}", "jit.dispatch", "level.read"}
+                need = {f"query.{mode}", "engine.dispatch", "level.read"}
                 if mode == "ssd":
                     need |= {"pipe.submit", "level.wait",
                              "level.relax", "level.decode"}

@@ -332,8 +332,8 @@ def run_data_parallel(side: int, devices, seed: int = 0) -> None:
     with sl.axis_rules(mesh, {"batch": "data"}):
         res, dp_first_s = timed(server.serve_stream, sources)
         res, dp_s = timed(server.serve_stream, sources)
-        raw = eng._ssd_jit(eng._plans, eng._core,
-                           jnp.asarray(ix.perm[sources]))
+        raw, _ = eng._ssd_jit(eng._plans, eng._core,
+                              jnp.asarray(ix.perm[sources]))
     rows_per_device = {s.data.shape[0] for s in raw.addressable_shards}
     check(len(raw.sharding.device_set) == len(devices)
           and rows_per_device == {BATCH // len(devices)},

@@ -75,6 +75,27 @@ def _knn_select(dist: np.ndarray, k: int
     return nodes, out
 
 
+def _program(impl, core_mode: str):
+    """``impl`` jitted with its core mode bound, under ``impl``'s own
+    name: the host dispatch reads ``PjitFunction(<impl>)`` and the
+    device program ``jit_<impl>``, not ``jit__unknown``."""
+    fn = functools.partial(impl, core_mode=core_mode)
+    fn.__name__ = impl.__name__
+    return jax.jit(fn)
+
+
+def _core_tags(counts, fill: int) -> dict:
+    """Span tags of a bellman core search's ``(rounds, settle)``: the
+    rounds it ran (``core_rounds``) and the sum of the settle rounds of
+    the batch's first ``fill`` rows (``core_row_rounds``); ``{}`` where
+    the program counted nothing."""
+    if counts is None:
+        return {}
+    rounds, settle = counts
+    return {"core_rounds": int(rounds),
+            "core_row_rounds": int(settle[:fill].sum())}
+
+
 def _plan_to_device(plan: SweepPlan):
     """Device-resident plan arrays, in the executor's scan order."""
     return (jnp.asarray(plan.dst), jnp.asarray(plan.src_idx),
@@ -164,6 +185,9 @@ class QueryEngine:
     #: Optional :class:`repro.obs.trace.Tracer` (DESIGN.md §11) — set by
     #: the streaming engine / server; ``None`` keeps every hook inert.
     tracer = None
+    #: While a program traces, the list :meth:`_core_update` appends the
+    #: bellman search's ``(rounds, settle)`` to (see :meth:`_forward_core`).
+    _core_counts = None
 
     def __init__(self, index: HoDIndex, core_mode: str = "closure",
                  use_pallas: bool = False, eps: float = 0.0,
@@ -175,14 +199,12 @@ class QueryEngine:
                              _plan_to_device(index.plan_b),
                              _plan_to_device(index.plan_core))
 
-        self._ssd_jit = jax.jit(functools.partial(
-            self._ssd_impl, core_mode=self.core_mode), static_argnames=())
-        self._sssp_jit = jax.jit(functools.partial(
-            self._sssp_impl, core_mode=self.core_mode))
-        self._p2p_jit = jax.jit(functools.partial(
-            self._p2p_impl, core_mode=self.core_mode))
-        self._within_jit = jax.jit(functools.partial(
-            self._within_impl, core_mode=self.core_mode))
+        # Each program returns ``(answer, counts)``: counts is the
+        # bellman core search's ``(rounds, settle)``, or None.
+        self._ssd_jit = _program(self._ssd_impl, self.core_mode)
+        self._sssp_jit = _program(self._sssp_impl, self.core_mode)
+        self._p2p_jit = _program(self._p2p_impl, self.core_mode)
+        self._within_jit = _program(self._within_impl, self.core_mode)
 
     def _init_engine(self, index: HoDIndex, core_mode: str,
                      use_pallas: bool, eps: float,
@@ -355,18 +377,25 @@ class QueryEngine:
         if core_mode == "bellman":
             # Iterate min-plus relaxation to fixpoint — the closest in-JAX
             # analogue of the paper's in-memory core scan. Converges in at
-            # most C-1 rounds; real cores settle in a handful.
+            # most C-1 rounds; real cores settle in a handful.  The loop
+            # counts its rounds (the last one changes nothing) and, per
+            # row, the last round in which the row changed.
             def cond(state):
-                d, changed, it = state
+                _, changed, it, _ = state
                 return changed & (it < c)
 
             def body(state):
-                d, _, it = state
+                d, _, it, settle = state
                 nd = jnp.minimum(d, _minplus_blocked(d, core))
-                return nd, jnp.any(nd < d), it + 1
+                moved = jnp.any(nd < d, axis=1)
+                it = it + 1
+                return nd, jnp.any(moved), it, jnp.where(moved, it, settle)
 
-            dc, _, _ = jax.lax.while_loop(
-                cond, body, (dc, jnp.bool_(True), jnp.int32(0)))
+            dc, _, rounds, settle = jax.lax.while_loop(
+                cond, body, (dc, jnp.bool_(True), jnp.int32(0),
+                             jnp.zeros(dc.shape[0], jnp.int32)))
+            if self._core_counts is not None:
+                self._core_counts.append((rounds, settle))
         else:  # closure
             if self.use_pallas:
                 from ..kernels.tropical_matmul.ops import minplus
@@ -388,24 +417,35 @@ class QueryEngine:
         return sl.shard(state, "batch", None)
 
     def _forward_core(self, plans: _Plans, core, sources_perm: jnp.ndarray,
-                      core_mode: str, level_body=None) -> jnp.ndarray:
+                      core_mode: str, level_body=None):
         """Forward search (§5.1) + core search (§5.2): the shared first
-        two phases of SSD, P2P, and threshold queries."""
+        two phases of SSD, P2P, and threshold queries.  Returns ``(dist,
+        counts)``: counts is the bellman search's ``(rounds, settle)``,
+        handed over by :meth:`_core_update` while it traces, or None."""
         dist = self._init_state(sources_perm)
-        dist = self._run_plan(dist, plans.f, level_body or self._relax_level)
+        with jax.named_scope("forward_sweep"):
+            dist = self._run_plan(dist, plans.f,
+                                  level_body or self._relax_level)
+        counts: list = []
         if core_mode != "dijkstra":
-            dist = self._core_update(dist, core, core_mode)
-        return dist
+            self._core_counts = counts
+            try:
+                with jax.named_scope("core_search"):
+                    dist = self._core_update(dist, core, core_mode)
+            finally:
+                self._core_counts = None
+        return dist, (counts[0] if counts else None)
 
     def _ssd_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
-                  core_mode: str) -> jnp.ndarray:
-        dist = self._forward_core(plans, core, sources_perm, core_mode)
-        dist = self._run_plan(dist, plans.b,            # backward search(§5.3)
-                              self._relax_level)
-        return dist
+                  core_mode: str):
+        dist, counts = self._forward_core(plans, core, sources_perm,
+                                          core_mode)
+        with jax.named_scope("backward_sweep"):         # §5.3
+            dist = self._run_plan(dist, plans.b, self._relax_level)
+        return dist, counts
 
     def _p2p_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
-                  targets_perm: jnp.ndarray, core_mode: str) -> jnp.ndarray:
+                  targets_perm: jnp.ndarray, core_mode: str):
         """Meet-in-the-middle P2P distances (DESIGN.md §7).
 
         Forward labels of ``s`` (forward sweep + core search — exactly
@@ -415,29 +455,33 @@ class QueryEngine:
         property (Theorem 1) every shortest path ascends, optionally
         crosses the core — folded into ``fwd`` by the core search — and
         descends, so some node ``m`` on it carries both labels."""
-        fwd = self._forward_core(plans, core, sources_perm, core_mode)
-        bwd = self._init_state(targets_perm)
-        bwd = self._run_plan(bwd, plans.b, self._relax_level_rev,
-                             reverse=True)
-        return jnp.min(fwd + bwd, axis=1)
+        fwd, counts = self._forward_core(plans, core, sources_perm,
+                                         core_mode)
+        with jax.named_scope("backward_labels"):
+            bwd = self._init_state(targets_perm)
+            bwd = self._run_plan(bwd, plans.b, self._relax_level_rev,
+                                 reverse=True)
+        with jax.named_scope("meet"):
+            return jnp.min(fwd + bwd, axis=1), counts
 
     def _within_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
-                     d: jnp.ndarray, core_mode: str) -> jnp.ndarray:
+                     d: jnp.ndarray, core_mode: str):
         """Distance-threshold SSD (DESIGN.md §7): the full sweep pipeline
         with the ``<= d`` mask applied inside every scan body, so labels
         past the threshold die where they arise instead of being
         filtered at the end — the masked levels are what the streaming
         engine skips reading entirely."""
         body = self._relax_level_thresh(d)
-        dist = self._forward_core(plans, core, sources_perm, core_mode,
-                                  level_body=body)
+        dist, counts = self._forward_core(plans, core, sources_perm,
+                                          core_mode, level_body=body)
         dist = jnp.where(dist <= d, dist, INF)          # mask core output
-        return self._run_plan(dist, plans.b, body)
+        with jax.named_scope("backward_sweep"):
+            return self._run_plan(dist, plans.b, body), counts
 
     def _sssp_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
                    core_mode: str):
         ix = self.index
-        dist = self._ssd_impl(plans, core, sources_perm, core_mode)
+        dist, counts = self._ssd_impl(plans, core, sources_perm, core_mode)
         s = sources_perm.shape[0]
         pred = jnp.full((s, ix.n_pad), -1, jnp.int32)
         recon = self._recon_level_body(dist)
@@ -445,42 +489,81 @@ class QueryEngine:
         # fixed `dist`, so the plan order commutes; the store-backed
         # engine exploits this by walking plans in reverse (cache
         # affinity with the distance pass) and stays bit-identical.
-        for plan in (plans.f, plans.c, plans.b):
-            pred = self._run_plan(pred, plan, recon)
-        return dist, pred
+        with jax.named_scope("reconstruct"):
+            for plan in (plans.f, plans.c, plans.b):
+                pred = self._run_plan(pred, plan, recon)
+        return (dist, pred), counts
 
     # ---------------------------------------------------------------- public
+    def _served(self, mode: str, rows: int, dispatch, readback):
+        """One public call in its three phases (DESIGN.md §11):
+        ``dispatch()`` looks the endpoints up, moves them to the device
+        and starts the work, returning ``(out, counts)`` as the programs
+        do; ``readback(out)`` copies the answer to the host in original
+        node order.  With a tracer each phase is a span
+        (``engine.dispatch``, ``engine.wait`` — a ``block_until_ready``
+        only a traced call makes — and ``engine.readback``) tagged with
+        the mode, the batch and fill the server bound (``fill`` defaults
+        to ``rows``) and, once read back, the core search's counts."""
+        tracer = self.tracer
+        if tracer is None:
+            return readback(dispatch()[0])
+        tags = {"mode": mode, "fill": rows, **tracer.tags()}
+        phases = [tracer.span(name, **tags) for name in
+                  ("engine.dispatch", "engine.wait", "engine.readback")]
+        with phases[0]:
+            out, counts = dispatch()
+        with phases[1]:
+            jax.block_until_ready((out, counts))
+        with phases[2]:
+            # One transfer for the answer and the counts together.
+            out, counts = jax.device_get((out, counts))
+            answer = readback(out)
+            counted = _core_tags(counts, tags["fill"])
+        for span in phases:       # a span's tags are read when exported
+            span.attrs.update(counted)
+        return answer
+
+    def _ends(self, nodes: np.ndarray) -> jnp.ndarray:
+        """Query endpoints in the index's node order, on the device."""
+        return jnp.asarray(self.index.perm[nodes])
+
+    def _unpermute(self, dist) -> np.ndarray:
+        """A ``[S, n_pad]`` state on the host in original node order."""
+        return np.asarray(dist)[:, self.index.perm]
+
     def ssd(self, sources: np.ndarray) -> np.ndarray:
         """Distances from each source to every node, original node order."""
         sources = np.asarray(sources, dtype=np.int32)
-        src_perm = self.index.perm[sources]
         if self.core_mode == "dijkstra":
-            dist = self._dijkstra_path(src_perm)
-        else:
-            dist = self._ssd_jit(self._plans, self._core,
-                                 jnp.asarray(src_perm))
-        return np.asarray(dist)[:, self.index.perm]
+            return self._unpermute(
+                self._dijkstra_path(self.index.perm[sources]))
+        return self._served(
+            "ssd", len(sources),
+            lambda: self._ssd_jit(self._plans, self._core,
+                                  self._ends(sources)),
+            self._unpermute)
 
     def sssp(self, sources: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """(dist, pred): pred[v] = node preceding v on a shortest path, -1
         for sources/unreachable. Node ids in original order."""
         sources = np.asarray(sources, dtype=np.int32)
-        src_perm = jnp.asarray(self.index.perm[sources])
         if self.core_mode == "dijkstra":
             # The host-Dijkstra core search lives outside the jit'd
             # pipeline; run it first, then reconstruction over the same
             # plans (eagerly — this mode is for validation, not serving).
-            dist = jnp.asarray(self._dijkstra_path(np.asarray(src_perm)))
+            dist = jnp.asarray(self._dijkstra_path(self.index.perm[sources]))
             pred = jnp.full((dist.shape[0], self.index.n_pad), -1,
                             jnp.int32)
             recon = self._recon_level_body(dist)
             for plan in (self._plans.f, self._plans.c, self._plans.b):
                 pred = self._run_plan(pred, plan, recon)
-        else:
-            dist, pred = self._sssp_jit(self._plans, self._core, src_perm)
-        dist = np.asarray(dist)[:, self.index.perm]
-        pred = np.asarray(pred)[:, self.index.perm]
-        return dist, pred
+            return self._unpermute(dist), self._unpermute(pred)
+        return self._served(
+            "sssp", len(sources),
+            lambda: self._sssp_jit(self._plans, self._core,
+                                   self._ends(sources)),
+            lambda out: tuple(self._unpermute(x) for x in out))
 
     def p2p(self, sources: np.ndarray, targets: np.ndarray) -> np.ndarray:
         """Point-to-point distances ``dist(sources[i], targets[i])``
@@ -492,17 +575,17 @@ class QueryEngine:
         """
         sources = np.asarray(sources, dtype=np.int32)
         targets = np.asarray(targets, dtype=np.int32)
-        src_perm = self.index.perm[sources]
-        tgt_perm = self.index.perm[targets]
         if self.core_mode == "dijkstra":
-            fwd = self._dijkstra_forward_core(src_perm)
-            bwd = self._init_state(jnp.asarray(tgt_perm))
+            fwd = self._dijkstra_forward_core(self.index.perm[sources])
+            bwd = self._init_state(self._ends(targets))
             bwd = self._run_plan(bwd, self._plans.b, self._relax_level_rev,
                                  reverse=True)
             return np.asarray(jnp.min(jnp.asarray(fwd) + bwd, axis=1))
-        return np.asarray(self._p2p_jit(self._plans, self._core,
-                                        jnp.asarray(src_perm),
-                                        jnp.asarray(tgt_perm)))
+        return self._served(
+            "p2p", len(sources),
+            lambda: self._p2p_jit(self._plans, self._core,
+                                  self._ends(sources), self._ends(targets)),
+            np.asarray)
 
     def ssd_within(self, sources: np.ndarray, d: float) -> np.ndarray:
         """Distance-threshold query (DESIGN.md §7): distances from each
@@ -511,18 +594,20 @@ class QueryEngine:
         their SSD distance.  ``d`` is a traced operand, so changing the
         threshold never recompiles."""
         sources = np.asarray(sources, dtype=np.int32)
-        src_perm = self.index.perm[sources]
-        if self.core_mode == "dijkstra":
-            body = self._relax_level_thresh(jnp.float32(d))
-            dist = self._init_state(jnp.asarray(src_perm))
-            dist = self._run_plan(dist, self._plans.f, body)
-            dist = self._core_dijkstra_host(np.array(dist))
-            dist = jnp.where(jnp.asarray(dist) <= d, jnp.asarray(dist), INF)
-            dist = self._run_plan(dist, self._plans.b, body)
-        else:
-            dist = self._within_jit(self._plans, self._core,
-                                    jnp.asarray(src_perm), jnp.float32(d))
-        return np.asarray(dist)[:, self.index.perm]
+        if self.core_mode != "dijkstra":
+            return self._served(
+                "within", len(sources),
+                lambda: self._within_jit(self._plans, self._core,
+                                         self._ends(sources),
+                                         jnp.float32(d)),
+                self._unpermute)
+        body = self._relax_level_thresh(jnp.float32(d))
+        dist = self._init_state(self._ends(sources))
+        dist = self._run_plan(dist, self._plans.f, body)
+        dist = self._core_dijkstra_host(np.array(dist))
+        dist = jnp.where(jnp.asarray(dist) <= d, jnp.asarray(dist), INF)
+        dist = self._run_plan(dist, self._plans.b, body)
+        return self._unpermute(dist)
 
     def knn(self, sources: np.ndarray, k: int
             ) -> Tuple[np.ndarray, np.ndarray]:

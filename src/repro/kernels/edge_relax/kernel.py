@@ -57,6 +57,7 @@ def relax_bucketed_pallas(gathered: jnp.ndarray, w: jnp.ndarray,
     grid = (ss // bs_, mm // bm_)
     out = pl.pallas_call(
         _relax_kernel,
+        name="edge_relax",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bs_, bm_, k), lambda i, j: (i, j, 0)),

@@ -59,6 +59,7 @@ def minplus_pallas(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
     grid = (mm // bm_, nn // bn_, kk // bk_)
     out = pl.pallas_call(
         functools.partial(_minplus_kernel, bk=bk_),
+        name="tropical_minplus",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kq: (i, kq)),

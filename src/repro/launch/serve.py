@@ -81,7 +81,7 @@ from ..core import (BuildConfig, QueryEngine, grid_road_graph, pack_index,
 from ..core.build_fast import build_hod_fast
 from ..core.io_sim import BlockDevice, IOStats
 from ..obs.metrics import Histogram, MetricsRegistry
-from ..obs.trace import span_if
+from ..obs.trace import bind_if, span_if
 
 __all__ = ["QueryResult", "ServerStats", "BatchIO", "ClassSLO",
            "QueryServer", "server_from_config", "mixed_request_stream",
@@ -532,9 +532,9 @@ class QueryServer:
                   if hasattr(self.engine, "pipeline_stats") else None)
         pbefore = pstats.snapshot() if pstats is not None else None
         t0 = time.perf_counter()
-        with span_if(self.tracer, f"query.{mode}",
-                     batch=self.stats.batches + 1, fill=fill), \
-             span_if(self.tracer, "jit.dispatch", mode=mode):
+        tags = {"batch": self.stats.batches + 1, "fill": fill}
+        with span_if(self.tracer, f"query.{mode}", **tags), \
+             bind_if(self.tracer, **tags):
             if mode == "sssp":
                 dist, pred = self.engine.sssp(batch)
             elif mode == "p2p":
@@ -550,65 +550,72 @@ class QueryServer:
             else:
                 dist, pred = self.engine.ssd(batch), None
         busy = time.perf_counter() - t0
-        self.stats.busy_seconds += busy
-        # Per-class execution estimate (deadline headroom, DESIGN.md
-        # §12): EWMA so one slow cold batch doesn't lock in forever.
-        prev = self._exec_ewma.get(mode)
-        a = self.EXEC_EWMA_ALPHA
-        self._exec_ewma[mode] = (busy if prev is None
-                                 else (1 - a) * prev + a * busy)
-        pdelta = (pstats - pbefore) if pstats is not None else None
-        if pdelta is not None:
-            self.stats.stall_seconds += pdelta.stall_model_s
-            self.stats.stall_wall_seconds += pdelta.stall_wall_s
-            if self.stats.ttfl_seconds == 0.0:
-                self.stats.ttfl_seconds = pdelta.ttfl_s
-        self.stats.batches += 1
-        self.stats.padded_slots += self.batch_size - fill
-        m = self.metrics
-        m.counter("server.batches").inc()
-        m.counter(f"server.batches.{mode}").inc()
-        m.counter("server.padded_slots").inc(self.batch_size - fill)
-        m.counter("server.busy_seconds").inc(busy)
-        if pdelta is not None:
-            m.counter("pipeline.stall_seconds").inc(pdelta.stall_model_s)
-        if self.store is None:
-            # In-memory engine: no real reads happen, charge the modeled
-            # sequential scan so I/O reporting stays meaningful.
-            self.device.sequential(self._mode_sweep_bytes[mode])
-            self._last_batch_bytes = float(self._mode_sweep_bytes[mode])
-        else:
-            # Store-backed: the page cache already metered every actual
-            # block read (miss) through the device — record the delta.
-            delta = self.store.cache.stats - before
-            self.stats.page_hits += delta.hits
-            self.stats.page_misses += delta.misses
-            self.stats.store_bytes_read += delta.bytes_read
-            self.stats.store_bytes_filled += delta.bytes_filled
-            self.batch_io.append(BatchIO(
-                batch=self.stats.batches, real_bytes=delta.bytes_read,
-                modeled_bytes=self._mode_sweep_bytes[mode],
-                page_hits=delta.hits,
-                page_misses=delta.misses,
-                filled_bytes=delta.bytes_filled,
-                stall_s=pdelta.stall_model_s if pdelta else 0.0))
-            self._last_batch_bytes = float(delta.bytes_read)
-            m.counter("page_cache.hits").inc(delta.hits)
-            m.counter("page_cache.misses").inc(delta.misses)
-            m.counter("store.bytes_read").inc(delta.bytes_read)
-            m.counter("store.bytes_filled").inc(delta.bytes_filled)
-            m.gauge("page_cache.hit_rate").set(
-                self.stats.page_hit_rate())
-        rows = []
-        for i, req in enumerate(self._keys(requests)):
-            if mode == "p2p":          # scalar answer per pair
-                row = (np.float32(dist[i]), None)
+        # The host's bookkeeping: stats, metrics, I/O charges, and one
+        # result-cache row per request.
+        with span_if(self.tracer, "server.resolve", batch=tags["batch"]):
+            self.stats.busy_seconds += busy
+            # Per-class execution estimate (deadline headroom, DESIGN.md
+            # §12): EWMA so one slow cold batch doesn't lock in forever.
+            prev = self._exec_ewma.get(mode)
+            a = self.EXEC_EWMA_ALPHA
+            self._exec_ewma[mode] = (busy if prev is None
+                                     else (1 - a) * prev + a * busy)
+            pdelta = (pstats - pbefore) if pstats is not None else None
+            if pdelta is not None:
+                self.stats.stall_seconds += pdelta.stall_model_s
+                self.stats.stall_wall_seconds += pdelta.stall_wall_s
+                if self.stats.ttfl_seconds == 0.0:
+                    self.stats.ttfl_seconds = pdelta.ttfl_s
+            self.stats.batches += 1
+            self.stats.padded_slots += self.batch_size - fill
+            m = self.metrics
+            m.counter("server.batches").inc()
+            m.counter(f"server.batches.{mode}").inc()
+            m.counter("server.padded_slots").inc(self.batch_size - fill)
+            m.counter("server.busy_seconds").inc(busy)
+            if pdelta is not None:
+                m.counter("pipeline.stall_seconds").inc(
+                    pdelta.stall_model_s)
+            if self.store is None:
+                # In-memory engine: no real reads happen, charge the
+                # modeled sequential scan so I/O reporting stays
+                # meaningful.
+                self.device.sequential(self._mode_sweep_bytes[mode])
+                self._last_batch_bytes = float(
+                    self._mode_sweep_bytes[mode])
             else:
-                row = (dist[i].copy(),
-                       None if pred is None else pred[i].copy())
-            self._cache_put(req, row, mode)
-            rows.append(row)
-        return rows
+                # Store-backed: the page cache already metered every
+                # actual block read (miss) through the device — record
+                # the delta.
+                delta = self.store.cache.stats - before
+                self.stats.page_hits += delta.hits
+                self.stats.page_misses += delta.misses
+                self.stats.store_bytes_read += delta.bytes_read
+                self.stats.store_bytes_filled += delta.bytes_filled
+                self.batch_io.append(BatchIO(
+                    batch=self.stats.batches, real_bytes=delta.bytes_read,
+                    modeled_bytes=self._mode_sweep_bytes[mode],
+                    page_hits=delta.hits,
+                    page_misses=delta.misses,
+                    filled_bytes=delta.bytes_filled,
+                    stall_s=pdelta.stall_model_s if pdelta else 0.0))
+                self._last_batch_bytes = float(delta.bytes_read)
+                m.counter("page_cache.hits").inc(delta.hits)
+                m.counter("page_cache.misses").inc(delta.misses)
+                m.counter("store.bytes_read").inc(delta.bytes_read)
+                m.counter("store.bytes_filled").inc(delta.bytes_filled)
+                m.gauge("page_cache.hit_rate").set(
+                    self.stats.page_hit_rate())
+            rows = []
+            for i, req in enumerate(self._keys(requests)):
+                if mode == "p2p":          # scalar answer per pair
+                    row = (np.float32(dist[i]), None)
+                else:
+                    row = (dist[i].copy(),
+                           None if pred is None else pred[i].copy())
+                self._cache_put(req, row, mode)
+                rows.append(row)
+            return rows
 
     def _observe(self, latency_s: float, cached: bool,
                  mode: Optional[str] = None) -> None:
@@ -872,7 +879,7 @@ class QueryServer:
             self.tracer.complete(
                 "coalesce.wait",
                 self.tracer.now() - int(wait_s * 1e9),
-                waiters=len(take))
+                waiters=len(take), batch=self.stats.batches + 1)
         groups: "collections.OrderedDict[str, List[_Pending]]" = \
             collections.OrderedDict()
         for entry in take:
@@ -891,16 +898,19 @@ class QueryServer:
                 continue
             share = self._last_batch_bytes / len(entries)
             now = self._now()
-            for (req, fut, t0, _), row in zip(entries, rows):
-                self.stats.requests += 1
-                self._observe(now - t0, cached=False, mode=mode)
-                src, tgt = req if isinstance(req, tuple) else (req, None)
-                if not fut.done():
-                    d, p, nd = self._row_fields(row, mode)
-                    fut.set_result(QueryResult(
-                        source=src, target=tgt, dist=d, pred=p,
-                        nodes=nd, mode=mode, latency_s=now - t0,
-                        batched_with=len(entries), io_bytes=share))
+            with span_if(self.tracer, "server.resolve",
+                         batch=self.stats.batches):
+                for (req, fut, t0, _), row in zip(entries, rows):
+                    self.stats.requests += 1
+                    self._observe(now - t0, cached=False, mode=mode)
+                    src, tgt = (req if isinstance(req, tuple)
+                                else (req, None))
+                    if not fut.done():
+                        d, p, nd = self._row_fields(row, mode)
+                        fut.set_result(QueryResult(
+                            source=src, target=tgt, dist=d, pred=p,
+                            nodes=nd, mode=mode, latency_s=now - t0,
+                            batched_with=len(entries), io_bytes=share))
 
     def _flush(self, include_partial: bool = True) -> None:
         """Flush every queue unconditionally (drain / legacy callers),

@@ -6,4 +6,5 @@
 from .metrics import (LATENCY_BUCKETS_MS, REGISTRY,  # noqa: F401
                       SCHEMA_VERSION, Counter, Gauge, Histogram,
                       MetricsRegistry, exp_buckets)
-from .trace import Tracer, span_if, validate_chrome_trace  # noqa: F401
+from .trace import (MIRRORED, Tracer, bind_if, span_if,  # noqa: F401
+                    validate_chrome_trace)

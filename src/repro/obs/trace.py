@@ -5,12 +5,26 @@ The serving stack's whole argument is I/O *attribution* — which reads
 a query caused, which it avoided, and how far the pipeline hid the
 rest behind compute.  Aggregate counters (``IOStats`` / ``CacheStats``
 / ``PipelineStats``) answer that for a workload; the :class:`Tracer`
-answers it for one query: every served request opens a root span
-(``query.ssd``, ``query.p2p``, …) whose children cover coalesce-wait,
-jit dispatch, and — per streamed level — the submit-side cache
-transaction, the io-thread pread, the decode-pool frame decode, and
-the query-thread reap/relax.  Exported as Chrome trace-event JSON
-(open in https://ui.perfetto.dev) plus a flat JSONL event log.
+answers it for one query: every served batch opens a root span
+(``query.ssd``, ``query.p2p``, …) whose children are the engine's
+phases — ``engine.dispatch`` (endpoints to the device, the program
+launched), ``engine.wait`` (until the device is done) and
+``engine.readback`` (the answer copied back, in node order) — followed
+by the server's ``server.resolve`` (stats, result cache, futures);
+beside them the retroactive ``coalesce.wait``, and per streamed level the
+submit-side cache transaction, the io-thread pread, the decode-pool
+frame decode, and the query-thread reap/relax.  Every span of a batch
+carries its ``batch`` id: the server binds it (:meth:`Tracer.bind`) and
+the engine reads it back (:meth:`Tracer.tags`).  Exported as Chrome
+trace-event JSON (open in https://ui.perfetto.dev) plus a flat JSONL
+event log.
+
+**The profiler's clock.**  The serving thread's phases
+(:data:`MIRRORED`) are also written into ``jax.profiler``'s trace as
+``TraceAnnotation`` host events, so they share the device trace's
+clock and name the device's idle gaps.  They follow one another and
+never overlap; the whole-batch parent is not mirrored, as it would
+cover every gap.  JAX is imported only when such a span opens.
 
 **Tracks.** Chrome traces group events by thread id, and B/E spans
 must nest *per thread*.  Events land on three kinds of tracks:
@@ -38,28 +52,40 @@ Perfetto's query view joins them back into one per-level story.
 **Overhead contract** (DESIGN.md §11): a ``None`` tracer is the off
 switch — every hook site guards with ``if tracer is not None`` (or
 :func:`span_if`), so disabled tracing adds one attribute load per
-site.  Enabled tracing buffers flat tuples in memory with a lock-free
-append (atomic under the GIL) and must stay within 5% of untraced
-serving throughput — asserted by the ``latency`` table in
-``benchmarks/serve_throughput.py``.  Tracing never changes answers or
-counter sequences: hooks only *observe* (asserted bit-identical in
-the bench and ``tests/test_obs.py``).
+site; the benchmark's end-to-end runs are made that way.  Enabled
+tracing buffers flat tuples in memory with a lock-free append (atomic
+under the GIL); the engine's traced call adds a ``block_until_ready``
+and one transfer of the core search's counts per batch.  It must stay
+within 5% of untraced serving on the chip (measured in PERF.md).
+Tracing never changes answers or counter sequences: hooks only
+*observe* (asserted bit-identical in ``tests/test_obs.py`` and
+``tests/test_engine_phases.py``).
 """
 from __future__ import annotations
 
 import json
 import threading
 import time
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["Tracer", "span_if", "validate_chrome_trace"]
+__all__ = ["MIRRORED", "Tracer", "bind_if", "span_if",
+           "validate_chrome_trace"]
+
+#: Spans also written into the profiler's trace: the serving thread's
+#: phases, which follow one another and never overlap, so an idle gap of
+#: the device is named by the phase the host was in.
+MIRRORED = frozenset({"engine.dispatch", "engine.wait", "engine.readback",
+                      "server.resolve"})
 
 
 class _Span:
-    """Context manager emitting a B/E pair on the tracer."""
+    """Context manager emitting a B/E pair on the tracer, and a
+    ``jax.profiler.TraceAnnotation`` inside it for a :data:`MIRRORED`
+    name.  ``attrs`` is the event's own dict: tags added to it before
+    the trace is read are exported with the span."""
 
-    __slots__ = ("_tracer", "name", "track", "attrs")
+    __slots__ = ("_tracer", "name", "track", "attrs", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str,
                  track: Optional[str], attrs: dict):
@@ -67,12 +93,20 @@ class _Span:
         self.name = name
         self.track = track
         self.attrs = attrs
+        self._mirror = None
 
     def __enter__(self) -> "_Span":
         self._tracer._emit("B", self.name, self.track, self.attrs)
+        if self.name in MIRRORED:
+            from jax.profiler import TraceAnnotation   # obs needs no JAX
+            self._mirror = TraceAnnotation(self.name)
+            self._mirror.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._mirror is not None:
+            self._mirror.__exit__(*exc)
+            self._mirror = None
         self._tracer._emit("E", self.name, self.track, None)
         return False
 
@@ -84,6 +118,13 @@ def span_if(tracer: Optional["Tracer"], name: str,
     if tracer is None:
         return nullcontext()
     return tracer.span(name, track=track, **attrs)
+
+
+def bind_if(tracer: Optional["Tracer"], **attrs):
+    """``tracer.bind(...)`` or an inert context when tracing is off."""
+    if tracer is None:
+        return nullcontext()
+    return tracer.bind(**attrs)
 
 
 class Tracer:
@@ -105,6 +146,7 @@ class Tracer:
         self._events: List[tuple] = []
         self._next_id = 0
         self._t0 = time.perf_counter_ns()
+        self._bound = threading.local()
 
     # ------------------------------------------------------------- emission
     def now(self) -> int:
@@ -129,6 +171,22 @@ class Tracer:
         else:
             tkey, tname = ("track", track), track
         self._events.append((ph, name, ts, tkey, tname, attrs, dur_ns))
+
+    @contextmanager
+    def bind(self, **attrs):
+        """Bind ``attrs`` on this thread for the ``with`` body: a layer
+        below reads them with :meth:`tags` and tags its own spans, so
+        every span of one batch carries the server's batch id."""
+        outer = self.tags()
+        self._bound.tags = {**outer, **attrs}
+        try:
+            yield
+        finally:
+            self._bound.tags = outer
+
+    def tags(self) -> dict:
+        """The attrs bound on this thread (:meth:`bind`), else ``{}``."""
+        return getattr(self._bound, "tags", {})
 
     def span(self, name: str, track: Optional[str] = None,
              **attrs) -> _Span:

@@ -268,7 +268,8 @@ def main() -> None:
         problems = validate_chrome_trace(doc)
         assert not problems, f"invalid Chrome trace: {problems[:3]}"
         names = {e["name"] for e in doc["traceEvents"]}
-        need = {"query.ssd", "query.p2p", "jit.dispatch", "pipe.submit",
+        need = {"query.ssd", "query.p2p", "engine.dispatch",
+                "engine.wait", "engine.readback", "pipe.submit",
                 "level.wait", "level.relax", "level.read",
                 "level.decode", "cache.hit", "cache.miss",
                 "device.read"}

@@ -269,13 +269,23 @@ class StreamingQueryEngine(QueryEngine):
             self.store.unpin_level(name, lvl)
 
     # ---------------------------------------------------------------- public
+    # Each call runs in the engine's three phases (QueryEngine._served):
+    # here the whole streamed sweep is the dispatch, and no count comes
+    # back.
     def ssd(self, sources: np.ndarray) -> np.ndarray:
         sources = np.asarray(sources, dtype=np.int32)
-        dist = self._ssd_stream(self.index.perm[sources])
-        return np.asarray(dist)[:, self.index.perm]
+        return self._served(
+            "ssd", len(sources),
+            lambda: (self._ssd_stream(self.index.perm[sources]), None),
+            self._unpermute)
 
     def sssp(self, sources: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         sources = np.asarray(sources, dtype=np.int32)
+        return self._served(
+            "sssp", len(sources), lambda: (self._sssp_stream(sources), None),
+            lambda out: tuple(self._unpermute(x) for x in out))
+
+    def _sssp_stream(self, sources: np.ndarray):
         try:
             # Distance pass pins the levels it streams: reconstruction
             # re-reads all of them immediately after (recon reuse).
@@ -294,8 +304,6 @@ class StreamingQueryEngine(QueryEngine):
         finally:
             for name in ("plan_f", "plan_b"):
                 self._unpin_plan(name)
-        dist = np.asarray(dist)[:, self.index.perm]
-        pred = np.asarray(pred)[:, self.index.perm]
         return dist, pred
 
     # -------------------------------------------- bounded sweeps (§7)
@@ -309,6 +317,14 @@ class StreamingQueryEngine(QueryEngine):
 
     def p2p(self, sources: np.ndarray, targets: np.ndarray,
             early_term: bool = True) -> np.ndarray:
+        """Point-to-point distances (:meth:`_p2p_stream`)."""
+        return self._served(
+            "p2p", len(sources),
+            lambda: (self._p2p_stream(sources, targets, early_term), None),
+            np.asarray)
+
+    def _p2p_stream(self, sources: np.ndarray, targets: np.ndarray,
+                    early_term: bool):
         """Point-to-point distances ``dist(sources[i], targets[i])`` by
         meet-in-the-middle (DESIGN.md §7), reading strictly less than a
         full SSD sweep:
@@ -349,9 +365,16 @@ class StreamingQueryEngine(QueryEngine):
                 cut = int(ix.level_ptr[int(self._level_ids_b[j - 1])])
                 if bool(jnp.all(best <= self._suffix_min(fwd, cut))):
                     break
-        return np.asarray(best)
+        return best
 
     def ssd_within(self, sources: np.ndarray, d: float) -> np.ndarray:
+        """All distances ``<= d`` (:meth:`_within_stream`)."""
+        return self._served(
+            "within", len(sources),
+            lambda: (self._within_stream(sources, d), None),
+            self._unpermute)
+
+    def _within_stream(self, sources: np.ndarray, d: float):
         """All distances ``<= d`` (rest ``+inf``), original node order.
 
         The threshold body clamps labels past ``d`` inside every level
@@ -381,7 +404,7 @@ class StreamingQueryEngine(QueryEngine):
                                          dist.shape[1])):
                 continue
             dist = self._thresh_step(dist, d, *self._read("plan_b", lvl))
-        return np.asarray(dist)[:, ix.perm]
+        return dist
 
     def knn(self, sources: np.ndarray, k: int
             ) -> Tuple[np.ndarray, np.ndarray]:

@@ -213,7 +213,8 @@ def test_tracer_is_a_pure_observer_in_memory(engine_ix):
     for a, b in zip(traced, plain):
         np.testing.assert_array_equal(a, b)
     names = {e["name"] for e in tr.events()}
-    assert {"query.ssd", "jit.dispatch"} <= names
+    assert {"query.ssd", "engine.dispatch", "engine.wait",
+            "engine.readback", "server.resolve"} <= names
     assert validate_chrome_trace(tr.chrome()) == []
     # the per-mode latency histogram saw every request
     h = reg.histogram("latency_ms.ssd")

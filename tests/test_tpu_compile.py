@@ -104,10 +104,12 @@ def test_ssd_compiles_batch_sharded_on_2x2(topo, one_chip):
     ``data`` mesh compiles (the Pallas kernel runs per shard) and leaves
     each chip a quarter of the ``[S, n_pad]`` state."""
     eng = _engine(32)
-    single = eng._ssd_jit.lower(*_ssd_operands(eng, one_chip)).compile()
+    # The program's state alone, without the core search's counts.
+    state = jax.jit(lambda *operands: eng._ssd_jit(*operands)[0])
+    single = state.lower(*_ssd_operands(eng, one_chip)).compile()
     mesh = sl.make_mesh((4,), ("data",), devices=topo.devices)
     with sl.axis_rules(mesh, {"batch": "data"}):
-        sharded = eng._ssd_jit.lower(
+        sharded = state.lower(
             *_ssd_operands(eng, NamedSharding(mesh, P()))).compile()
     out = sharded.output_shardings
     assert out.spec == P("data")
