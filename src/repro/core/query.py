@@ -141,17 +141,27 @@ def _per_batch_shard(fn, batched: Tuple[bool, ...], *args):
         out_specs=b)(*args)
 
 
-def _minplus_blocked(a: jnp.ndarray, b: jnp.ndarray,
-                     block_k: int = 256) -> jnp.ndarray:
-    """out[s, j] = min_k a[s, k] + b[k, j], accumulated over k blocks."""
+def _block_rows(b: jnp.ndarray, block_k: int = 256) -> jnp.ndarray:
+    """``b`` [K, N] as the ``[kb, block_k, N]`` k blocks that
+    :func:`_minplus_blocks` consumes, padded with ``+inf`` rows (which
+    never win a minimum) to a whole number of blocks.  A function of
+    ``b`` alone: a loop that multiplies by a constant ``b`` blocks it
+    once, outside the loop, since XLA does not hoist the pad itself."""
+    k_dim, n_dim = b.shape
+    b = jnp.pad(b, ((0, (-k_dim) % block_k), (0, 0)),
+                constant_values=jnp.inf)
+    return b.reshape(-1, block_k, n_dim)
+
+
+def _minplus_blocks(a: jnp.ndarray, b_blocks: jnp.ndarray) -> jnp.ndarray:
+    """out[s, j] = min_k a[s, k] + b[k, j] over ``b`` already blocked by
+    :func:`_block_rows`, accumulated block by block; only the small
+    ``[S, K]`` ``a`` is padded here."""
+    kb, block_k, n_dim = b_blocks.shape
     s_dim, k_dim = a.shape
-    pad = (-k_dim) % block_k
-    if pad:
-        a = jnp.pad(a, ((0, 0), (0, pad)), constant_values=jnp.inf)
-        b = jnp.pad(b, ((0, pad), (0, 0)), constant_values=jnp.inf)
-    kb = a.shape[1] // block_k
+    a = jnp.pad(a, ((0, 0), (0, kb * block_k - k_dim)),
+                constant_values=jnp.inf)
     a_blocks = a.reshape(s_dim, kb, block_k).transpose(1, 0, 2)
-    b_blocks = b.reshape(kb, block_k, b.shape[1])
 
     def body(acc, blk):
         ab, bb = blk
@@ -159,9 +169,15 @@ def _minplus_blocked(a: jnp.ndarray, b: jnp.ndarray,
                                        axis=1))
         return acc, None
 
-    init = jnp.full((s_dim, b.shape[1]), jnp.inf, a.dtype)
+    init = jnp.full((s_dim, n_dim), jnp.inf, a.dtype)
     out, _ = jax.lax.scan(body, init, (a_blocks, b_blocks))
     return out
+
+
+def _minplus_blocked(a: jnp.ndarray, b: jnp.ndarray,
+                     block_k: int = 256) -> jnp.ndarray:
+    """out[s, j] = min_k a[s, k] + b[k, j], accumulated over k blocks."""
+    return _minplus_blocks(a, _block_rows(b, block_k))
 
 
 class QueryEngine:
@@ -380,13 +396,15 @@ class QueryEngine:
             # most C-1 rounds; real cores settle in a handful.  The loop
             # counts its rounds (the last one changes nothing) and, per
             # row, the last round in which the row changed.
+            blocks = _block_rows(core)
+
             def cond(state):
                 _, changed, it, _ = state
                 return changed & (it < c)
 
             def body(state):
                 d, _, it, settle = state
-                nd = jnp.minimum(d, _minplus_blocked(d, core))
+                nd = jnp.minimum(d, _minplus_blocks(d, blocks))
                 moved = jnp.any(nd < d, axis=1)
                 it = it + 1
                 return nd, jnp.any(moved), it, jnp.where(moved, it, settle)
