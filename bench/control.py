@@ -5,12 +5,13 @@
 
 One set-up of the cell at its own size, then per seed a window of the
 cell's own traffic.  For the sample of answers a run would check it
-prints the comparison's numbers twice: the program's answers against
-the reference (the lower reading), and the answers of the
-configuration's control put in the program's place (the upper reading).
-The control is the reference with one guarantee the configuration
-states broken (``yardstick.reference.control_for``).  Not a benchmark
-run: it prints no result line.
+prints the comparison's numbers for the program's answers against the
+reference (the lower reading), and for the answers of each of the
+configuration's controls put in the program's place (the upper
+reading).  A control is the reference with one guarantee the
+configuration states broken, one per guarantee
+(``yardstick.reference.controls_for``).  Not a benchmark run: it prints
+no result line.
 """
 import argparse
 import dataclasses
@@ -20,31 +21,34 @@ import sys
 
 
 def readings(cell, arcs, truth, answered, seed: int) -> dict:
-    """The comparison's numbers for the program's ``answered`` and for
-    the control's answers to the same sampled requests."""
+    """The comparison's numbers for the program's ``answered`` and, by
+    control, for each control's answers to the same sampled requests."""
     from yardstick import check, reference
 
     mode = cell.traffic["mode"]
     k = int(cell.config["check"][f"{mode}_answers"])
     picks = check.sample(answered, k, seed)
     guarantees = cell.config["guarantees"]
-    far = 0.0 if "labels" in guarantees else max(
-        _farthest(mode, answered[i].request, truth) for i in picks)
-    ctl = reference.control_for(guarantees, arcs, far)
-    swapped = list(answered)
-    for i in picks:
-        swapped[i] = with_control(answered[i], mode, ctl)
+    far = None
+    if reference.counts_hops(guarantees, arcs):
+        far = max(_farthest(mode, answered[i].request, truth)
+                  for i in picks)
+    controls = {}
+    for name, ctl in reference.controls_for(guarantees, arcs, far).items():
+        swapped = list(answered)
+        for i in picks:
+            swapped[i] = with_control(answered[i], mode, ctl)
+        controls[name] = check.compare(mode, swapped, truth, k,
+                                       seed).numbers
     return {"program": check.compare(mode, answered, truth, k,
                                      seed).numbers,
-            "control": check.compare(mode, swapped, truth, k,
-                                     seed).numbers,
-            "farthest": far}
+            "controls": controls, "farthest": far}
 
 
 def _farthest(mode: str, request, truth) -> float:
-    if mode == "p2p":
-        return truth.p2p(*request)
-    return max(d for d in truth.ssd(request) if math.isfinite(d))
+    """The largest finite distance in the reference's answer."""
+    dists = [truth.p2p(*request)] if mode == "p2p" else truth.ssd(request)
+    return max((d for d in dists if math.isfinite(d)), default=0.0)
 
 
 def with_control(a, mode: str, ctl):

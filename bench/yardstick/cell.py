@@ -149,7 +149,7 @@ def setup(cell: spec.Cell, mode: str, trace: bool,
     if t_start is not None:
         timings["startup_s"] = time.perf_counter() - t_start
     t = time.perf_counter()
-    arcs = graphs.generate(conf["graph"])
+    arcs = graphs.generate(conf["graph"], cell.root)
     timings["generate_s"] = time.perf_counter() - t
     t = time.perf_counter()
     ix, _ = build_served_index(from_edges(arcs.n, arcs.src, arcs.dst,

@@ -6,21 +6,30 @@ the benchmark's own arc arrays, exact Python floats.  On integer arc
 lengths every distance is an exact integer, so the engine's float32
 answers must equal it exactly.
 
-Each configuration's control lives beside it (``control_for``): the
-reference with one guarantee the configuration states broken, as a later
-change might be tempted to break it.  It has to fail the comparison:
+Each guarantee a configuration states has a control (``controls_for``):
+the reference with that guarantee broken, as a later change might be
+tempted to break it.  Each has to fail the comparison wherever the data
+lets it:
 
-* labels at a stated precision: every tentative distance rounded to the
-  precision below (``bfloat16`` for float32);
-* exact hop counts on unit arcs, with no precision stated:
-  ``hop_capped`` paths of at most one arc less than the farthest
+* ``labels`` at a stated precision: every tentative distance rounded to
+  the precision below (``bfloat16`` for float32).  bfloat16 holds every
+  integer up to 256 exactly, so this control fails only where distances
+  pass 256;
+* ``arcs: directed``: ``reversed``, the reference over every arc turned
+  around (``dst -> src``, same length), as a search that walks the
+  in-arc index in place of the out-arc one would answer;
+* exact hop counts (no ``labels``, every arc of unit length):
+  ``hop_capped``, paths of at most one arc less than the farthest
   sampled answer, as a search stopped one round early would give.
+
+A guarantee value with no control is refused, so none goes unguarded.
+A new control is a case in ``control`` and a rule in ``controls_for``.
 """
 from __future__ import annotations
 
 import heapq
 import math
-from typing import Callable, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -106,22 +115,46 @@ class HopCapped(Reference):
 PRECISION_BELOW = {"float32": "bfloat16"}
 
 
-def control_for(guarantees: dict, arcs, farthest: float) -> Reference:
-    """The control of a configuration stating ``guarantees`` over
-    ``arcs``; ``farthest`` is the largest distance the reference gives
-    among the sampled answers."""
-    if "labels" in guarantees:
-        return control(arcs, PRECISION_BELOW[guarantees["labels"]])
-    if not np.all(arcs.w == 1.0):
-        raise ValueError("a hop cap needs unit arcs, where a distance "
-                         "counts arcs")
-    return control(arcs, "hop_capped", hops=int(farthest) - 1)
+def counts_hops(guarantees: dict, arcs) -> bool:
+    """Whether answers are exact hop counts: no label precision stated
+    and every arc of unit length.  Then ``hop_capped`` applies and
+    ``controls_for`` needs the farthest sampled answer."""
+    return "labels" not in guarantees and bool(np.all(arcs.w == 1.0))
+
+
+def controls_for(guarantees: dict, arcs,
+                 farthest: Optional[float]) -> Dict[str, Reference]:
+    """One control per guarantee of ``guarantees`` over ``arcs``, by
+    name.  ``farthest``, the largest distance the reference gives among
+    the sampled answers, is read only where ``counts_hops``.  A stated
+    guarantee with no control, or none to break, raises ``ValueError``."""
+    controls = {}
+    for key, value in guarantees.items():
+        if key == "labels" and value in PRECISION_BELOW:
+            name = PRECISION_BELOW[value]
+        elif key == "arcs" and value == "directed":
+            name = "reversed"
+        elif key == "answers":
+            continue
+        else:
+            raise ValueError(f"no control breaks the guarantee "
+                             f"{key}: {value!r}")
+        controls[name] = control(arcs, name)
+    if counts_hops(guarantees, arcs):
+        controls["hop_capped"] = control(arcs, "hop_capped",
+                                         hops=int(farthest) - 1)
+    if not controls:
+        raise ValueError("no control: the answers are neither labels at a "
+                         "stated precision, nor directed, nor hop counts")
+    return controls
 
 
 def control(arcs, name: str, hops: Optional[int] = None) -> Reference:
     """The control ``name`` over ``arcs``."""
     if name == "bfloat16":
         return Reference(arcs, rounding=_round_bf16)
+    if name == "reversed":
+        return Reference(arcs._replace(src=arcs.dst, dst=arcs.src))
     if name == "hop_capped":
         return HopCapped(arcs, hops)
     raise ValueError(f"unknown control {name!r}")

@@ -2,10 +2,13 @@
 
 ``BENCHMARK.json`` at the checkout's root lists the cells; each names a
 configuration (whose entry gives its file) and a traffic mix, found at
-``bench/traffic/<traffic>.json``.  A per-layer metric ``<name>`` is read
-by ``bench/metrics/<name>.py``, which defines ``read(readings)``.  So a
-configuration, a traffic mix, a cell or a metric is added by adding
-files and entries: nothing here names one.
+``bench/traffic/<traffic>.json``.  A configuration's ``graph`` block
+names its kind: ``bench/graphs/<kind>.py``, which defines
+``arcs(**params)`` over the block's other keys.  A per-layer metric
+``<name>`` is read by ``bench/metrics/<name>.py``, which defines
+``read(readings)``.  So a configuration, a graph kind, a traffic mix, a
+cell or a metric is added by adding files and entries: nothing here
+names one.
 """
 from __future__ import annotations
 
@@ -24,7 +27,8 @@ _NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
 class SpecError(ValueError):
-    """A cell, configuration, traffic mix or metric cannot be resolved."""
+    """A cell, configuration, graph kind, traffic mix or metric cannot be
+    resolved, or a graph kind's generator returned malformed arcs."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,19 +92,35 @@ def resolve(cell_name: str, root: str = ROOT) -> Cell:
         root=root)
 
 
-def load_reader(metric: str, root: str = ROOT
-                ) -> Callable[[object], Optional[float]]:
-    """``read`` of ``bench/metrics/<metric>.py``."""
-    path = os.path.join(root, "bench", "metrics",
-                        _checked_name(metric, "metric") + ".py")
+def _load_function(folder: str, name: str, what: str, function: str,
+                   root: str) -> Callable:
+    """``function`` of ``<root>/bench/<folder>/<name>.py``."""
+    path = os.path.join(root, "bench", folder,
+                        _checked_name(name, what) + ".py")
     if not os.path.exists(path):
-        raise SpecError(f"no reader for metric {metric!r} "
+        raise SpecError(f"no file for {what} {name!r} "
                         f"({os.path.relpath(path, root)})")
-    mod_name = "bench_metric_" + re.sub(r"\W", "_", metric)
+    mod_name = f"bench_{folder}_" + re.sub(r"\W", "_", name)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    fn = getattr(mod, function, None)
+    if not callable(fn):
+        raise SpecError(f"{os.path.relpath(path, root)} defines no "
+                        f"{function}()")
+    return fn
+
+
+def load_reader(metric: str, root: str = ROOT
+                ) -> Callable[[object], Optional[float]]:
+    """``read`` of ``bench/metrics/<metric>.py``."""
+    return _load_function("metrics", metric, "metric", "read", root)
+
+
+def load_graph(kind: str, root: str = ROOT) -> Callable[..., object]:
+    """``arcs`` of ``bench/graphs/<kind>.py``, the generator of a graph
+    kind."""
+    return _load_function("graphs", kind, "graph kind", "arcs", root)
 
 
 def readers(cell: Cell) -> Dict[str, Callable]:

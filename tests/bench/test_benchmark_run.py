@@ -99,19 +99,50 @@ def test_a_fault_in_the_timed_path_is_not_correct(tiny, cell, fault,
     assert out["compared"]["wrong"]["value"] > 0
 
 
+#: The largest whole number below which a precision holds every integer
+#: exactly: a control rounding to it cannot fail on shorter distances.
+EXACT_INTEGERS = {"bfloat16": 2 ** 8}
+
+
+def assert_controls_fail(row, farthest):
+    """Every control in ``row`` fails the comparison, save a precision
+    control where no sampled distance passes that precision's exact
+    integers (it then reads ``wrong`` 0); at least one fails."""
+    assert row["program"] == {"wrong": 0, "unanswered": 0}
+    assert row["controls"]
+    for name, numbers in row["controls"].items():
+        if farthest <= EXACT_INTEGERS.get(name, -1):
+            assert numbers["wrong"] == 0, name
+        else:
+            assert numbers["wrong"] > 0, name
+    assert any(n["wrong"] > 0 for n in row["controls"].values())
+
+
+def sampled_farthest(cell, answered, truth, seed):
+    """The largest finite reference distance among the answers a run
+    samples."""
+    from yardstick import check
+
+    mode = cell.traffic["mode"]
+    picks = check.sample(answered,
+                         int(cell.config["check"][f"{mode}_answers"]), seed)
+    return max(control._farthest(mode, answered[i].request, truth)
+               for i in picks)
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_the_control_in_the_programs_place_is_not_correct(tiny, cell):
-    """The configuration's control (``bench/control.py``), put in the
-    program's place for the answers a run samples, fails the comparison
-    that the program's own answers pass."""
+    """Each of the configuration's controls (``bench/control.py``), put
+    in the program's place for the answers a run samples, fails the
+    comparison that the program's own answers pass."""
     c = spec.resolve(cell, tiny)
     mix = traffic.validate(dict(c.traffic))
     served = cellmod.setup(c, mix["mode"], trace=False)
     res = cellmod.drive(served, mix, 3000000029, 0.5)
-    row = control.readings(c, served.arcs, Reference(served.arcs),
-                           res.answered, 3000000029)
-    assert row["program"] == {"wrong": 0, "unanswered": 0}
-    assert row["control"]["wrong"] > 0
+    truth = Reference(served.arcs)
+    row = control.readings(c, served.arcs, truth, res.answered, 3000000029)
+    assert_controls_fail(row, sampled_farthest(c, res.answered, truth,
+                                               3000000029))
 
 
 def test_result_line_is_the_last_line_and_limits_follow_on_stderr(

@@ -6,10 +6,14 @@ import math
 import os
 import re
 
+import numpy as np
 import pytest
 from benchsupport import ROOT, copy_benchmark, dump, load, shrink
 from yardstick import cell as cellmod
-from yardstick import spec, traffic
+from yardstick import check, spec, traffic
+from yardstick.reference import Reference
+
+import control
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -121,6 +125,95 @@ def test_new_files_are_found_with_no_code_edit(tmp_path, monkeypatch):
     out = cellmod.run(args, 0.0, root=root, require_chip=False)
     assert out["correct"] is True and out["attempted"] > 0
     assert set(out["metrics"]) == {"p2p_p99_ms", "setup_s"}
+
+
+#: A directed, weighted preferential-attachment generator, as a later
+#: configuration would bring it: each link in one direction, lengths 1-10.
+WEB_GENERATOR = """\
+import numpy as np
+from yardstick.graphs import Arcs
+
+
+def arcs(nodes, m_per_node, seed):
+    rng = np.random.default_rng(seed)
+    src, dst = [], []
+    repeated = list(range(m_per_node))
+    for v in range(m_per_node, nodes):
+        for p in rng.choice(len(repeated), size=m_per_node, replace=False):
+            u = repeated[p]
+            if rng.random() < 0.5:
+                src.append(v)
+                dst.append(u)
+            else:
+                src.append(u)
+                dst.append(v)
+            repeated.append(u)
+        repeated.extend([v] * m_per_node)
+    src = np.asarray(src, dtype=np.int64)
+    w = rng.integers(1, 11, size=src.shape[0]).astype(np.float64)
+    return Arcs(nodes, src, np.asarray(dst, dtype=np.int64), w)
+"""
+
+
+def test_a_directed_graph_is_added_as_files(tmp_path, monkeypatch):
+    """A directed graph kind, a configuration that states its arcs are
+    directed, and an SSD closed-loop and a P2P open-loop cell, added as
+    files and entries only: both cells run correct at their CPU test
+    size with unreachable answers among those sampled, and the
+    ``reversed`` control fails where the ``bfloat16`` one cannot, since
+    no distance reaches 256."""
+    root = copy_benchmark(tmp_path)
+    with open(os.path.join(root, "bench/graphs/web_pa.py"), "w") as f:
+        f.write(WEB_GENERATOR)
+    conf = load(root, "bench/configs/road-grid-g256.json")
+    conf.update(name="web-pa", reduced=[],
+                graph={"kind": "web_pa", "nodes": 60000, "m_per_node": 4,
+                       "seed": 3},
+                guarantees={"answers": "exact distance along arcs",
+                            "labels": "float32", "arcs": "directed"},
+                cpu_test={"graph": {"nodes": 300}, "batch": 8})
+    dump(root, "bench/configs/web-pa.json", conf)
+    bench = load(root, "BENCHMARK.json")
+    bench["configs"].append(
+        {"name": "web-pa", "source": "https://example.org",
+         "file": "bench/configs/web-pa.json", "reduced": [], "why": "test"})
+    reports = {"web-pa.ssd-closed": {"sources_per_s", "setup_s"},
+               "web-pa.ic13-open": {"p2p_p50_ms", "p2p_p90_ms", "setup_s"}}
+    for name in reports:
+        bench["workloads"].append(
+            {"name": name, "config": "web-pa",
+             "traffic": name.split(".", 1)[1], "chips": 1, "why": "test"})
+    for metric in bench["end_to_end"]:
+        metric.get("workloads", []).extend(
+            name for name, names in reports.items()
+            if metric["name"] in names)
+    dump(root, "BENCHMARK.json", bench)
+    shrink(root)
+
+    import repro.launch.compile_cache as cc
+    monkeypatch.setattr(cc, "enable_compile_cache", lambda: "off")
+    for name, names in reports.items():
+        args = cellmod.parse(["--workload", name, "--seed", "3000000031",
+                              "--seconds", "0.5"])
+        out = cellmod.run(args, 0.0, root=root, require_chip=False)
+        assert out["correct"] is True, (name, out["compared"])
+        assert out["attempted"] > 0 and set(out["metrics"]) == names
+
+        cell = spec.resolve(name, root)
+        mix = traffic.validate(dict(cell.traffic))
+        served = cellmod.setup(cell, mix["mode"], trace=False)
+        assert served.arcs.n == 300
+        res = cellmod.drive(served, mix, 3000000037, 0.5)
+        k = int(cell.config["check"][f"{mix['mode']}_answers"])
+        sampled = [res.answered[i].answer
+                   for i in check.sample(res.answered, k, 3000000037)]
+        assert any(np.isinf(a).any() for a in sampled), name
+        row = control.readings(cell, served.arcs, Reference(served.arcs),
+                               res.answered, 3000000037)
+        assert row["program"] == {"wrong": 0, "unanswered": 0}
+        assert set(row["controls"]) == {"bfloat16", "reversed"}
+        assert row["controls"]["reversed"]["wrong"] > 0, name
+        assert row["controls"]["bfloat16"]["wrong"] == 0, name
 
 
 def test_configuration_files_state_source_and_cuts():

@@ -1,12 +1,16 @@
 """The yardstick's arithmetic on inputs whose answers are known: window
 and percentile rules, the trace reduction, the least-work count and the
 peak table, the generators and the reference."""
+import hashlib
+import json
 import math
+import os
 
 import numpy as np
 import pytest
-import benchsupport  # noqa: F401  (puts bench/ on the path)
-from yardstick import graphs, peaks, reference, stats, tracing, traffic, work
+from benchsupport import ROOT, copy_benchmark
+from yardstick import (graphs, peaks, reference, spec, stats, tracing,
+                       traffic, work)
 from yardstick.loops import Answered, LoopResult
 
 
@@ -162,24 +166,111 @@ def test_peak_table_refuses_an_unknown_device():
 
 
 # ------------------------------------------------- generators, reference
+def _grid(side, seed, **kw):
+    return graphs.generate({"kind": "grid_road", "side": side,
+                            "seed": seed, **kw})
+
+
+def _arcs_digest(arcs) -> str:
+    h = hashlib.sha256(np.int64(arcs.n).tobytes())
+    for a, dtype in ((arcs.src, "<i8"), (arcs.dst, "<i8"), (arcs.w, "<f8")):
+        h.update(np.ascontiguousarray(a, dtype=dtype).tobytes())
+    return h.hexdigest()
+
+
+def _config(name):
+    entry = next(c for c in spec.load_benchmark(ROOT)["configs"]
+                 if c["name"] == name)
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        return json.load(f)
+
+
+#: SHA-256 over ``n``, ``src``, ``dst`` and ``w`` of each configuration's
+#: full-size arcs, as the generators made them when they lived in
+#: ``yardstick/graphs.py``: moving a generator must not move its data.
+PINNED_ARCS = {
+    "road-grid-g256":
+        "dd9e139bbc43ce850f93b858381edbc25755d1fbc3eeb03d99c20fd30b26b7fa",
+    "social-pa-24k":
+        "6aadff9b6e59ce61d673d44261274da20fff20056b9dd69e8644504cb22aff0d",
+}
+
+
+@pytest.mark.parametrize("config", sorted(PINNED_ARCS))
+def test_full_size_arcs_keep_their_pinned_digest(config):
+    arcs = graphs.generate(_config(config)["graph"])
+    assert _arcs_digest(arcs) == PINNED_ARCS[config]
+
+
+#: The controls each configuration's stated guarantees give.
+PINNED_CONTROLS = {"road-grid-g256": {"bfloat16"},
+                   "social-pa-24k": {"hop_capped"}}
+
+
+@pytest.mark.parametrize("config", sorted(PINNED_CONTROLS))
+def test_each_configuration_keeps_its_controls(config):
+    conf = _config(config)
+    arcs = graphs.generate({**conf["graph"], **conf["cpu_test"]["graph"]})
+    got = reference.controls_for(conf["guarantees"], arcs, farthest=3.0)
+    assert set(got) == PINNED_CONTROLS[config]
+
+
 def test_generators_repeat_the_repositorys_own():
     from repro.core import (from_edges, grid_road_graph, power_law_digraph,
                             symmetrize)
 
+    social = {"kind": "power_law_social", "persons": 120, "m_per_node": 5,
+              "seed": 4}
     for mine, theirs in (
-            (graphs.grid_road(9, seed=4), grid_road_graph(9, seed=4)),
-            (graphs.power_law_social(120, 5, seed=4),
+            (_grid(9, 4), grid_road_graph(9, seed=4)),
+            (graphs.generate(social),
              symmetrize(power_law_digraph(120, 5, seed=4)))):
         g = from_edges(mine.n, mine.src, mine.dst, mine.w)
         for a, b in zip(g.edge_list(), theirs.edge_list()):
             assert np.array_equal(a, b)
 
 
+@pytest.mark.parametrize("kind", ["no_such_kind", "../x", "grid_road/x"])
+def test_an_unknown_or_escaping_graph_kind_is_refused(kind):
+    with pytest.raises(spec.SpecError):
+        graphs.generate({"kind": kind, "side": 4, "seed": 1})
+
+
+#: Bodies of a generator that returns malformed arcs.
+BAD_ARCS = {
+    "negative_weight": "Arcs(3, src, dst, np.array([1.0, -1.0]))",
+    "zero_weight": "Arcs(3, src, dst, np.array([1.0, 0.0]))",
+    "nan_weight": "Arcs(3, src, dst, np.array([1.0, np.nan]))",
+    "endpoint_out_of_range": "Arcs(3, src, np.array([1, 3]), w)",
+    "negative_endpoint": "Arcs(3, np.array([0, -1]), dst, w)",
+    "int32_endpoints": "Arcs(3, src.astype(np.int32), dst, w)",
+    "integer_weights": "Arcs(3, src, dst, w.astype(np.int64))",
+    "lengths_differ": "Arcs(3, src, dst, w[:1])",
+    "no_nodes": "Arcs(0, src[:0], dst[:0], w[:0])",
+    "not_arcs": "(3, src, dst, w)",
+}
+
+
+@pytest.mark.parametrize("fault", sorted(BAD_ARCS))
+def test_a_generator_that_returns_malformed_arcs_is_refused(tmp_path,
+                                                            fault):
+    root = copy_benchmark(tmp_path)
+    with open(os.path.join(root, "bench", "graphs", "bad.py"), "w") as f:
+        f.write("import numpy as np\n"
+                "from yardstick.graphs import Arcs\n\n\n"
+                "def arcs():\n"
+                "    src, dst = np.array([0, 1]), np.array([1, 2])\n"
+                "    w = np.array([1.0, 2.0])\n"
+                f"    return {BAD_ARCS[fault]}\n")
+    with pytest.raises(spec.SpecError):
+        graphs.generate({"kind": "bad"}, root)
+
+
 def test_reference_agrees_with_the_engine_on_a_side_16_grid():
     from repro.core import QueryEngine, from_edges
     from repro.launch.serve import build_served_index
 
-    arcs = graphs.grid_road(16, seed=5)
+    arcs = _grid(16, 5)
     ix, _ = build_served_index(from_edges(arcs.n, arcs.src, arcs.dst,
                                           arcs.w))
     eng = QueryEngine(ix, use_pallas=False)
@@ -194,7 +285,7 @@ def test_reference_agrees_with_the_engine_on_a_side_16_grid():
 
 
 def test_controls_break_the_guarantee_they_name():
-    road = graphs.grid_road(100, seed=6)
+    road = _grid(100, 6)
     exact = reference.Reference(road)
     bf16 = reference.control(road, "bfloat16")
     far = exact.ssd(0)
@@ -202,6 +293,29 @@ def test_controls_break_the_guarantee_they_name():
     assert reference.control(road, "hop_capped", hops=1000).ssd(0) == far
     capped = reference.control(road, "hop_capped", hops=5).ssd(0)
     assert math.isinf(capped[-1]) and capped[1] == far[1]
+
+
+def test_the_reversed_control_walks_every_arc_backwards():
+    # 0 -> 1 -> 2 and 0 -> 2 the long way; nothing leads back to 0
+    arcs = graphs.Arcs(3, np.array([0, 1, 0]), np.array([1, 2, 2]),
+                       np.array([1.0, 2.0, 5.0]))
+    assert reference.Reference(arcs).ssd(0) == [0.0, 1.0, 3.0]
+    rev = reference.controls_for({"labels": "float32", "arcs": "directed"},
+                                 arcs, farthest=None)
+    assert set(rev) == {"bfloat16", "reversed"}
+    assert rev["reversed"].ssd(0) == [0.0, math.inf, math.inf]
+    assert rev["reversed"].p2p(2, 0) == 3.0
+    assert rev["bfloat16"].ssd(0) == [0.0, 1.0, 3.0]
+
+
+@pytest.mark.parametrize("guarantees", [
+    {"labels": "float16"}, {"arcs": "undirected"}, {"durability": "fsync"},
+    {"answers": "exact"}])
+def test_a_guarantee_with_no_control_is_refused(guarantees):
+    """A value no control breaks, and weighted answers with nothing
+    stated to break, raise rather than go unguarded."""
+    with pytest.raises(ValueError):
+        reference.controls_for(guarantees, _grid(4, 1), farthest=5.0)
 
 
 def test_a_pool_seed_gives_every_seed_the_same_batches():
