@@ -84,16 +84,12 @@ def _program(impl, core_mode: str):
     return jax.jit(fn)
 
 
-def _core_tags(counts, fill: int) -> dict:
-    """Span tags of a bellman core search's ``(rounds, settle)``: the
-    rounds it ran (``core_rounds``) and the sum of the settle rounds of
-    the batch's first ``fill`` rows (``core_row_rounds``); ``{}`` where
-    the program counted nothing."""
-    if counts is None:
-        return {}
-    rounds, settle = counts
-    return {"core_rounds": int(rounds),
-            "core_row_rounds": int(settle[:fill].sum())}
+def _count_tags(counts: dict, fill: int) -> dict:
+    """Span tags of a program's counts, under their own names: a scalar
+    count as it is, a per-row ``[S]`` count summed over the batch's
+    first ``fill`` rows (the rest are padding)."""
+    return {name: int(v) if np.ndim(v) == 0 else int(np.sum(v[:fill]))
+            for name, v in counts.items()}
 
 
 def _plan_to_device(plan: SweepPlan):
@@ -111,6 +107,7 @@ class _Plans(NamedTuple):
     f: tuple   # forward search (§5.1)
     b: tuple   # backward search (§5.3)
     c: tuple   # core edges, read only by SSSP reconstruction (§6)
+    perm: jnp.ndarray   # [n] original id -> permuted id (SSD answers)
 
 
 def _dense_core_adjacency(ix: HoDIndex) -> np.ndarray:
@@ -201,8 +198,8 @@ class QueryEngine:
     #: Optional :class:`repro.obs.trace.Tracer` (DESIGN.md §11) — set by
     #: the streaming engine / server; ``None`` keeps every hook inert.
     tracer = None
-    #: While a program traces, the list :meth:`_core_update` appends the
-    #: bellman search's ``(rounds, settle)`` to (see :meth:`_forward_core`).
+    #: While a program traces, the dict :meth:`_core_update` puts the
+    #: bellman search's counts in (see :meth:`_forward_core`).
     _core_counts = None
 
     def __init__(self, index: HoDIndex, core_mode: str = "closure",
@@ -213,10 +210,12 @@ class QueryEngine:
         index.ensure_plans(k_cap)   # no-op for pack_index/v2+-load indexes
         self._plans = _Plans(_plan_to_device(index.plan_f),
                              _plan_to_device(index.plan_b),
-                             _plan_to_device(index.plan_core))
+                             _plan_to_device(index.plan_core),
+                             jnp.asarray(index.perm, jnp.int32))
 
-        # Each program returns ``(answer, counts)``: counts is the
-        # bellman core search's ``(rounds, settle)``, or None.
+        # Each program returns ``(answer, counts)``: counts maps a span
+        # tag's name to a scalar or per-row ``[S]`` count (see
+        # :func:`_count_tags`), ``{}`` where the program counts nothing.
         self._ssd_jit = _program(self._ssd_impl, self.core_mode)
         self._sssp_jit = _program(self._sssp_impl, self.core_mode)
         self._p2p_jit = _program(self._p2p_impl, self.core_mode)
@@ -413,7 +412,8 @@ class QueryEngine:
                 cond, body, (dc, jnp.bool_(True), jnp.int32(0),
                              jnp.zeros(dc.shape[0], jnp.int32)))
             if self._core_counts is not None:
-                self._core_counts.append((rounds, settle))
+                self._core_counts.update(core_rounds=rounds,
+                                         core_row_rounds=settle)
         else:  # closure
             if self.use_pallas:
                 from ..kernels.tropical_matmul.ops import minplus
@@ -438,13 +438,14 @@ class QueryEngine:
                       core_mode: str, level_body=None):
         """Forward search (§5.1) + core search (§5.2): the shared first
         two phases of SSD, P2P, and threshold queries.  Returns ``(dist,
-        counts)``: counts is the bellman search's ``(rounds, settle)``,
-        handed over by :meth:`_core_update` while it traces, or None."""
+        counts)``: counts holds the bellman search's ``core_rounds`` and
+        per-row ``core_row_rounds`` (each row's settle round), handed
+        over by :meth:`_core_update` while it traces, or is ``{}``."""
         dist = self._init_state(sources_perm)
         with jax.named_scope("forward_sweep"):
             dist = self._run_plan(dist, plans.f,
                                   level_body or self._relax_level)
-        counts: list = []
+        counts: dict = {}
         if core_mode != "dijkstra":
             self._core_counts = counts
             try:
@@ -452,15 +453,32 @@ class QueryEngine:
                     dist = self._core_update(dist, core, core_mode)
             finally:
                 self._core_counts = None
-        return dist, (counts[0] if counts else None)
+        return dist, counts
 
-    def _ssd_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
-                  core_mode: str):
+    def _ssd_state(self, plans: _Plans, core, sources_perm: jnp.ndarray,
+                   core_mode: str):
+        """The ``[S, n_pad]`` label state in the index's node order, and
+        the program's counts."""
         dist, counts = self._forward_core(plans, core, sources_perm,
                                           core_mode)
         with jax.named_scope("backward_sweep"):         # §5.3
             dist = self._run_plan(dist, plans.b, self._relax_level)
-        return dist, counts
+        # Per row, the real nodes left at +inf: columns from ``n`` on are
+        # the sentinel and padding.
+        with jax.named_scope("count_unreachable"):
+            real = jnp.arange(dist.shape[1]) < self.index.n
+            unreachable = jnp.sum(jnp.isinf(dist) & real, axis=1,
+                                  dtype=jnp.int32)
+        return dist, {**counts, "unreachable": unreachable}
+
+    def _ssd_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
+                  core_mode: str):
+        """SSD answers ``[S, n]`` in original node order: the state
+        gathered on the device, so the host receives a batch's answer in
+        one transfer and makes no pass over it."""
+        dist, counts = self._ssd_state(plans, core, sources_perm, core_mode)
+        with jax.named_scope("unpermute"):
+            return jnp.take(dist, plans.perm, axis=1), counts
 
     def _p2p_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
                   targets_perm: jnp.ndarray, core_mode: str):
@@ -499,7 +517,8 @@ class QueryEngine:
     def _sssp_impl(self, plans: _Plans, core, sources_perm: jnp.ndarray,
                    core_mode: str):
         ix = self.index
-        dist, counts = self._ssd_impl(plans, core, sources_perm, core_mode)
+        dist, counts = self._ssd_state(plans, core, sources_perm,
+                                       core_mode)
         s = sources_perm.shape[0]
         pred = jnp.full((s, ix.n_pad), -1, jnp.int32)
         recon = self._recon_level_body(dist)
@@ -522,7 +541,8 @@ class QueryEngine:
         (``engine.dispatch``, ``engine.wait`` — a ``block_until_ready``
         only a traced call makes — and ``engine.readback``) tagged with
         the mode, the batch and fill the server bound (``fill`` defaults
-        to ``rows``) and, once read back, the core search's counts."""
+        to ``rows``) and, once read back, the program's counts
+        (:func:`_count_tags`)."""
         tracer = self.tracer
         if tracer is None:
             return readback(dispatch()[0])
@@ -534,10 +554,10 @@ class QueryEngine:
         with phases[1]:
             jax.block_until_ready((out, counts))
         with phases[2]:
-            # One transfer for the answer and the counts together.
-            out, counts = jax.device_get((out, counts))
+            # The answer's path is the untraced one; the counts, a few
+            # ints, follow it in a transfer of their own.
             answer = readback(out)
-            counted = _core_tags(counts, tags["fill"])
+            counted = _count_tags(jax.device_get(counts), tags["fill"])
         for span in phases:       # a span's tags are read when exported
             span.attrs.update(counted)
         return answer
@@ -547,8 +567,10 @@ class QueryEngine:
         return jnp.asarray(self.index.perm[nodes])
 
     def _unpermute(self, dist) -> np.ndarray:
-        """A ``[S, n_pad]`` state on the host in original node order."""
-        return np.asarray(dist)[:, self.index.perm]
+        """A ``[S, n_pad]`` state on the host in original node order,
+        row-major: ``np.take`` gathers along each row, where ``[:, perm]``
+        would build a column-major array, with every row strided."""
+        return np.take(np.asarray(dist), self.index.perm, axis=1)
 
     def ssd(self, sources: np.ndarray) -> np.ndarray:
         """Distances from each source to every node, original node order."""
@@ -560,7 +582,7 @@ class QueryEngine:
             "ssd", len(sources),
             lambda: self._ssd_jit(self._plans, self._core,
                                   self._ends(sources)),
-            self._unpermute)
+            np.asarray)
 
     def sssp(self, sources: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """(dist, pred): pred[v] = node preceding v on a shortest path, -1

@@ -606,13 +606,17 @@ class QueryServer:
                 m.counter("store.bytes_filled").inc(delta.bytes_filled)
                 m.gauge("page_cache.hit_rate").set(
                     self.stats.page_hit_rate())
+            # A row the result cache keeps is a copy, so that it does not
+            # hold its batch's whole answer alive; without a cache each
+            # request gets a view of that answer.
+            own = np.copy if self.cache_entries > 0 else np.asarray
             rows = []
             for i, req in enumerate(self._keys(requests)):
                 if mode == "p2p":          # scalar answer per pair
                     row = (np.float32(dist[i]), None)
                 else:
-                    row = (dist[i].copy(),
-                           None if pred is None else pred[i].copy())
+                    row = (own(dist[i]),
+                           None if pred is None else own(pred[i]))
                 self._cache_put(req, row, mode)
                 rows.append(row)
             return rows

@@ -55,7 +55,7 @@ switch — every hook site guards with ``if tracer is not None`` (or
 site; the benchmark's end-to-end runs are made that way.  Enabled
 tracing buffers flat tuples in memory with a lock-free append (atomic
 under the GIL); the engine's traced call adds a ``block_until_ready``
-and one transfer of the core search's counts per batch.  It must stay
+and one transfer of the program's counts per batch.  It must stay
 within 5% of untraced serving on the chip (measured in PERF.md).
 Tracing never changes answers or counter sequences: hooks only
 *observe* (asserted bit-identical in ``tests/test_obs.py`` and

@@ -270,19 +270,19 @@ class StreamingQueryEngine(QueryEngine):
 
     # ---------------------------------------------------------------- public
     # Each call runs in the engine's three phases (QueryEngine._served):
-    # here the whole streamed sweep is the dispatch, and no count comes
-    # back.
+    # here the whole streamed sweep is the dispatch, and it counts
+    # nothing (``{}``).
     def ssd(self, sources: np.ndarray) -> np.ndarray:
         sources = np.asarray(sources, dtype=np.int32)
         return self._served(
             "ssd", len(sources),
-            lambda: (self._ssd_stream(self.index.perm[sources]), None),
+            lambda: (self._ssd_stream(self.index.perm[sources]), {}),
             self._unpermute)
 
     def sssp(self, sources: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         sources = np.asarray(sources, dtype=np.int32)
         return self._served(
-            "sssp", len(sources), lambda: (self._sssp_stream(sources), None),
+            "sssp", len(sources), lambda: (self._sssp_stream(sources), {}),
             lambda out: tuple(self._unpermute(x) for x in out))
 
     def _sssp_stream(self, sources: np.ndarray):
@@ -320,7 +320,7 @@ class StreamingQueryEngine(QueryEngine):
         """Point-to-point distances (:meth:`_p2p_stream`)."""
         return self._served(
             "p2p", len(sources),
-            lambda: (self._p2p_stream(sources, targets, early_term), None),
+            lambda: (self._p2p_stream(sources, targets, early_term), {}),
             np.asarray)
 
     def _p2p_stream(self, sources: np.ndarray, targets: np.ndarray,
@@ -371,7 +371,7 @@ class StreamingQueryEngine(QueryEngine):
         """All distances ``<= d`` (:meth:`_within_stream`)."""
         return self._served(
             "within", len(sources),
-            lambda: (self._within_stream(sources, d), None),
+            lambda: (self._within_stream(sources, d), {}),
             self._unpermute)
 
     def _within_stream(self, sources: np.ndarray, d: float):

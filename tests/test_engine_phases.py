@@ -3,7 +3,8 @@
 
 Past ``pack_index``'s ``closure_limit`` the engine searches the core in
 bellman mode, and its programs return the search's round count and each
-row's settle round beside the answer.  A traced public call records
+row's settle round beside the answer; the SSD programs add each row's
+count of unreachable nodes.  A traced public call records
 ``engine.dispatch``, ``engine.wait`` and ``engine.readback``, tagged
 with the server's batch id and fill and with those counts; the serving
 thread's phases follow one another without overlap, and reach the
@@ -68,19 +69,22 @@ def test_core_counts_match_a_numpy_recount(engine):
     rounds, settle = _recount(engine, SOURCES)
     assert rounds > 1 and settle.max() == rounds - 1
     ends = jnp.asarray(engine.index.perm[SOURCES])
-    _, (r_ssd, s_ssd) = engine._ssd_jit(engine._plans, engine._core, ends)
-    _, (r_p2p, s_p2p) = engine._p2p_jit(
+    _, c_ssd = engine._ssd_jit(engine._plans, engine._core, ends)
+    _, c_p2p = engine._p2p_jit(
         engine._plans, engine._core, ends,
         jnp.asarray(engine.index.perm[TARGETS]))
-    for r, s in ((r_ssd, s_ssd), (r_p2p, s_p2p)):
-        assert int(r) == rounds
-        np.testing.assert_array_equal(np.asarray(s), settle)
+    assert set(c_ssd) == {"core_rounds", "core_row_rounds", "unreachable"}
+    assert set(c_p2p) == {"core_rounds", "core_row_rounds"}
+    for c in (c_ssd, c_p2p):
+        assert int(c["core_rounds"]) == rounds
+        np.testing.assert_array_equal(np.asarray(c["core_row_rounds"]),
+                                      settle)
 
     tracer, fill = Tracer(), 4
     engine.tracer = tracer
     try:
         with tracer.bind(batch=7, fill=fill):
-            engine.ssd(SOURCES)
+            answer = engine.ssd(SOURCES)
     finally:
         engine.tracer = None
     spans = tracer.spans()
@@ -88,7 +92,9 @@ def test_core_counts_match_a_numpy_recount(engine):
     for s in spans:
         assert s["args"] == {"mode": "ssd", "batch": 7, "fill": fill,
                              "core_rounds": rounds,
-                             "core_row_rounds": int(settle[:fill].sum())}
+                             "core_row_rounds": int(settle[:fill].sum()),
+                             "unreachable": int(np.isinf(
+                                 answer[:fill]).sum())}
 
 
 def test_answers_are_bit_identical_with_a_tracer(bellman_ix):

@@ -102,9 +102,9 @@ def test_ssd_program_size_does_not_grow_with_index(one_chip):
 def test_ssd_compiles_batch_sharded_on_2x2(topo, one_chip):
     """``serve --data-parallel``: the SSD batch sharded over a 4-chip
     ``data`` mesh compiles (the Pallas kernel runs per shard) and leaves
-    each chip a quarter of the ``[S, n_pad]`` state."""
+    each chip a quarter of the ``[S, n]`` answer."""
     eng = _engine(32)
-    # The program's state alone, without the core search's counts.
+    # The program's answer alone, without its counts.
     state = jax.jit(lambda *operands: eng._ssd_jit(*operands)[0])
     single = state.lower(*_ssd_operands(eng, one_chip)).compile()
     mesh = sl.make_mesh((4,), ("data",), devices=topo.devices)
@@ -113,6 +113,6 @@ def test_ssd_compiles_batch_sharded_on_2x2(topo, one_chip):
             *_ssd_operands(eng, NamedSharding(mesh, P()))).compile()
     out = sharded.output_shardings
     assert out.spec == P("data")
-    assert out.shard_shape((S, eng.index.n_pad)) == (S // 4, eng.index.n_pad)
+    assert out.shard_shape((S, eng.index.n)) == (S // 4, eng.index.n)
     assert sharded.memory_analysis().output_size_in_bytes == \
         single.memory_analysis().output_size_in_bytes // 4
