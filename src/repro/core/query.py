@@ -10,8 +10,10 @@ a static-shape :class:`~repro.core.index.SweepPlan` (DESIGN.md §5):
   and same-rank nodes are never adjacent, so each node's distance is final
   before its out-edges are relaxed (single-pass DAG sweep);
 * **core**: one min-plus (tropical) matmul against the precomputed core
-  closure (beyond-paper; the paper-faithful iterative/Dijkstra modes are
-  kept for validation);
+  closure where the core is small (beyond-paper), else bellman rounds to
+  a fixpoint, each over the core's arcs or, where the core is dense
+  enough, a min-plus with its dense adjacency (the paper-faithful
+  Dijkstra mode is kept for validation);
 * **backward**: plan levels descend rank — the paper's heap-free linear
   scan, verbatim.
 
@@ -54,6 +56,22 @@ from .index import HoDIndex, SweepPlan, node_levels, plan_level_ids
 __all__ = ["QueryEngine", "dijkstra_reference"]
 
 INF = jnp.float32(jnp.inf)
+
+#: A bellman round relaxes the core's arcs (``plan_core``'s ``[M_pad,
+#: K]`` slots) where the dense round's ``C * C`` cells are at least this
+#: many times as many; see :func:`sparse_core_round`.  Measured per
+#: round on one TPU v5e at 32 sources, over cores of 22,980-26,953
+#: nodes: a dense cell costs 40.8-42.4 ps and an arc slot 2.54-2.60 ns,
+#: so the arcs win from 60-64 cells a slot on.
+SPARSE_CORE_RATIO = 64
+
+
+def sparse_core_round(c: int, m_pad: int, k: int) -> bool:
+    """The bellman round's form for a ``c``-node core whose arcs
+    ``plan_core`` packs as ``[m_pad, k]``: over the arcs where the
+    dense ``[c, c]`` min-plus does ``SPARSE_CORE_RATIO`` times their
+    work or more, else dense."""
+    return c * c >= SPARSE_CORE_RATIO * m_pad * k
 
 
 def _knn_select(dist: np.ndarray, k: int
@@ -106,7 +124,7 @@ class _Plans(NamedTuple):
     time) would then grow with the index."""
     f: tuple   # forward search (§5.1)
     b: tuple   # backward search (§5.3)
-    c: tuple   # core edges, read only by SSSP reconstruction (§6)
+    c: tuple   # core edges: the sparse bellman round, SSSP reconstruction
     perm: jnp.ndarray   # [n] original id -> permuted id (SSD answers)
 
 
@@ -182,8 +200,10 @@ class QueryEngine:
 
     core_mode:
       * ``"closure"``  — beyond-paper: single tropical matmul (default)
-      * ``"bellman"``  — in-JAX iterative min-plus to fixpoint (diameter-
-                          bounded), closest in spirit to scanning G_c
+      * ``"bellman"``  — in-JAX rounds of relaxation to fixpoint
+                          (diameter-bounded), closest in spirit to
+                          scanning G_c: each over the core's arcs, or a
+                          dense min-plus where the core is dense enough
       * ``"dijkstra"`` — paper-faithful host-side heap Dijkstra on the core
 
     Forward/backward sweeps and SSSP reconstruction all run through the
@@ -205,9 +225,9 @@ class QueryEngine:
     def __init__(self, index: HoDIndex, core_mode: str = "closure",
                  use_pallas: bool = False, eps: float = 0.0,
                  interpret: Optional[bool] = None, k_cap: int = 16):
-        self._init_engine(index, core_mode, use_pallas, eps, interpret)
-
         index.ensure_plans(k_cap)   # no-op for pack_index/v2+-load indexes
+        self._init_engine(index, core_mode, use_pallas, eps, interpret,
+                          core_plan=True)
         self._plans = _Plans(_plan_to_device(index.plan_f),
                              _plan_to_device(index.plan_b),
                              _plan_to_device(index.plan_core),
@@ -223,12 +243,15 @@ class QueryEngine:
 
     def _init_engine(self, index: HoDIndex, core_mode: str,
                      use_pallas: bool, eps: float,
-                     interpret: Optional[bool]) -> None:
+                     interpret: Optional[bool],
+                     core_plan: bool = False) -> None:
         """Plan-independent engine state: everything a sweep level body
         or core search needs that is NOT a device-resident SweepPlan.
         Shared with the store-backed streaming engine
         (`repro.storage.stream`), which feeds plan levels from the page
-        cache instead of uploading them whole."""
+        cache instead of uploading them whole.  ``core_plan`` says that
+        the programs receive ``plan_core`` on the device, which the
+        sparse bellman round needs."""
         if core_mode not in ("closure", "bellman", "dijkstra"):
             raise ValueError(core_mode)
         if core_mode == "closure" and index.n_core \
@@ -248,14 +271,24 @@ class QueryEngine:
         # provably-inert levels (everything below the query endpoints).
         self._level_ids_f = plan_level_ids(index, forward=True)
         self._level_ids_b = plan_level_ids(index, forward=False)
+        #: Whether a bellman round relaxes the core's arcs: decided from
+        #: the index, so a program compiled in bellman mode takes the
+        #: form the served engine runs, whatever this engine's mode.
+        pc = index.plan_core
+        self.core_sparse = bool(core_plan and pc.l_pad and
+                                sparse_core_round(index.n_core, pc.m_pad,
+                                                  pc.k_fix))
         # The [C, C] matrix the jitted core search reads — the closure,
-        # or the dense adjacency the bellman mode iterates — passed to
-        # every jitted query as an operand.  The dijkstra mode searches
-        # the core CSR on the host and needs neither.
+        # or the dense adjacency the dense bellman round iterates —
+        # passed to every jitted query as an operand.  The sparse round
+        # reads ``plan_core`` instead and gets an empty one; the
+        # dijkstra mode searches the core CSR on the host.
         if core_mode == "closure":
             self._core = jnp.asarray(index.core_closure)
-        elif core_mode == "bellman":
+        elif core_mode == "bellman" and not self.core_sparse:
             self._core = jnp.asarray(_dense_core_adjacency(index))
+        elif core_mode == "bellman":
+            self._core = jnp.zeros((0, 0), jnp.float32)
         else:
             self._core = None
 
@@ -379,41 +412,26 @@ class QueryEngine:
         return body
 
     # ------------------------------------------------------------------ SSD
-    def _core_update(self, dist: jnp.ndarray, core: jnp.ndarray,
+    def _core_update(self, dist: jnp.ndarray, core,
                      core_mode: str) -> jnp.ndarray:
-        """Core search (§5.2) over ``core``: the dense adjacency in
-        bellman mode, the all-pairs closure in closure mode."""
+        """Core search (§5.2) over ``core``: in bellman mode rounds to a
+        fixpoint, over the core's arcs (``core`` is then ``plan_core``
+        on the device) where :attr:`core_sparse`, else over the dense
+        adjacency; in closure mode one min-plus with the all-pairs
+        closure."""
         ix = self.index
         c = ix.n_core
         if c == 0:
             return dist
+        if core_mode == "bellman" and self.core_sparse:
+            return self._bellman(dist, self._arc_round(core), sparse=True)
         lo = ix.n_noncore
         dc = jax.lax.dynamic_slice_in_dim(dist, lo, c, axis=1)
         if core_mode == "bellman":
-            # Iterate min-plus relaxation to fixpoint — the closest in-JAX
-            # analogue of the paper's in-memory core scan. Converges in at
-            # most C-1 rounds; real cores settle in a handful.  The loop
-            # counts its rounds (the last one changes nothing) and, per
-            # row, the last round in which the row changed.
             blocks = _block_rows(core)
-
-            def cond(state):
-                _, changed, it, _ = state
-                return changed & (it < c)
-
-            def body(state):
-                d, _, it, settle = state
-                nd = jnp.minimum(d, _minplus_blocks(d, blocks))
-                moved = jnp.any(nd < d, axis=1)
-                it = it + 1
-                return nd, jnp.any(moved), it, jnp.where(moved, it, settle)
-
-            dc, _, rounds, settle = jax.lax.while_loop(
-                cond, body, (dc, jnp.bool_(True), jnp.int32(0),
-                             jnp.zeros(dc.shape[0], jnp.int32)))
-            if self._core_counts is not None:
-                self._core_counts.update(core_rounds=rounds,
-                                         core_row_rounds=settle)
+            dc = self._bellman(
+                dc, lambda d: jnp.minimum(d, _minplus_blocks(d, blocks)),
+                sparse=False)
         else:  # closure
             if self.use_pallas:
                 from ..kernels.tropical_matmul.ops import minplus
@@ -423,6 +441,70 @@ class QueryEngine:
             else:
                 dc = _minplus_blocked(dc, core)
         return jax.lax.dynamic_update_slice_in_dim(dist, dc, lo, axis=1)
+
+    def _bellman(self, d: jnp.ndarray, step, sparse: bool) -> jnp.ndarray:
+        """Iterate the Jacobi round ``step`` (labels -> labels, each
+        candidate read from the round's input) to a fixpoint — the
+        closest in-JAX analogue of the paper's in-memory core scan.
+        Converges in at most C-1 rounds; real cores settle in a handful.
+        The loop counts its rounds (the last one changes nothing) and,
+        per row, the last round in which the row changed; ``core_sparse``
+        says which form ran."""
+        c = self.index.n_core
+
+        def cond(state):
+            _, changed, it, _ = state
+            return changed & (it < c)
+
+        def body(state):
+            d, _, it, settle = state
+            nd = step(d)
+            moved = jnp.any(nd < d, axis=1)
+            it = it + 1
+            return nd, jnp.any(moved), it, jnp.where(moved, it, settle)
+
+        d, _, rounds, settle = jax.lax.while_loop(
+            cond, body, (d, jnp.bool_(True), jnp.int32(0),
+                         jnp.zeros(d.shape[0], jnp.int32)))
+        if self._core_counts is not None:
+            self._core_counts.update(core_rounds=rounds,
+                                     core_row_rounds=settle,
+                                     core_sparse=jnp.int32(sparse))
+        return d
+
+    @staticmethod
+    def _arc_round(plan):
+        """The sparse bellman round over ``plan_core``'s one level, on
+        the whole ``[S, n_pad]`` state: per row the minimum over its K
+        in-arcs of ``d[src] + w``, scatter-min merged into its core
+        destination, as :meth:`_relax_level` does.  Ids are permuted
+        global ids; padding slots and rows read and write the sentinel
+        column at ``+inf``.  Every relayout of the plan is made here,
+        once per batch, outside the round loop: the slots go K-major,
+        so the minimum runs over K with M on the lanes, and the gather
+        and scatter indices are built whole."""
+        dst, src_idx, w, _, row_valid, level_mask = plan
+        valid = (row_valid[0] & level_mask[0])[:, None]
+        src_t = src_idx[0].T[..., None]                    # [K, M, 1]
+        w_t = jnp.where(valid, w[0], INF).T                 # [K, M]
+        dst = dst[0][:, None]              # [M, 1] ascending, padding last
+        gather = jax.lax.GatherDimensionNumbers(
+            offset_dims=(0,), collapsed_slice_dims=(1,),
+            start_index_map=(1,))
+        scatter = jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(0,), inserted_window_dims=(1,),
+            scatter_dims_to_operand_dims=(1,))
+        in_bounds = jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS
+
+        def step(d):
+            arcs = jax.lax.gather(d, src_t, gather, (d.shape[0], 1),
+                                  mode=in_bounds)           # [S, K, M]
+            cand = jnp.min(arcs + w_t, axis=1)               # [S, M]
+            return jax.lax.scatter_min(d, dst, cand, scatter,
+                                       indices_are_sorted=True,
+                                       mode=in_bounds)
+
+        return step
 
     def _init_state(self, nodes_perm: jnp.ndarray) -> jnp.ndarray:
         """[S, n_pad] all-+inf label state with 0 at each row's node.
@@ -440,12 +522,16 @@ class QueryEngine:
         two phases of SSD, P2P, and threshold queries.  Returns ``(dist,
         counts)``: counts holds the bellman search's ``core_rounds`` and
         per-row ``core_row_rounds`` (each row's settle round), handed
-        over by :meth:`_core_update` while it traces, or is ``{}``."""
+        over by :meth:`_core_update` while it traces, with
+        ``core_sparse`` (1 where the rounds ran over the core's arcs),
+        or is ``{}``."""
         dist = self._init_state(sources_perm)
         with jax.named_scope("forward_sweep"):
             dist = self._run_plan(dist, plans.f,
                                   level_body or self._relax_level)
         counts: dict = {}
+        if core_mode == "bellman" and self.core_sparse:
+            core = plans.c       # the sparse round reads the core's arcs
         if core_mode != "dijkstra":
             self._core_counts = counts
             try:

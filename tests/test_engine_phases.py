@@ -73,10 +73,12 @@ def test_core_counts_match_a_numpy_recount(engine):
     _, c_p2p = engine._p2p_jit(
         engine._plans, engine._core, ends,
         jnp.asarray(engine.index.perm[TARGETS]))
-    assert set(c_ssd) == {"core_rounds", "core_row_rounds", "unreachable"}
-    assert set(c_p2p) == {"core_rounds", "core_row_rounds"}
+    assert set(c_ssd) == {"core_rounds", "core_row_rounds", "core_sparse",
+                          "unreachable"}
+    assert set(c_p2p) == {"core_rounds", "core_row_rounds", "core_sparse"}
     for c in (c_ssd, c_p2p):
         assert int(c["core_rounds"]) == rounds
+        assert int(c["core_sparse"]) == 0
         np.testing.assert_array_equal(np.asarray(c["core_row_rounds"]),
                                       settle)
 
@@ -93,6 +95,7 @@ def test_core_counts_match_a_numpy_recount(engine):
         assert s["args"] == {"mode": "ssd", "batch": 7, "fill": fill,
                              "core_rounds": rounds,
                              "core_row_rounds": int(settle[:fill].sum()),
+                             "core_sparse": 0,
                              "unreachable": int(np.isinf(
                                  answer[:fill]).sum())}
 

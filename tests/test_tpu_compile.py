@@ -4,8 +4,9 @@ The TPU compiler is installed even where no chip is attached, and it
 refuses what the Pallas interpreter accepts: a lane-axis slice it cannot
 prove aligned, a kernel it cannot partition, a program that does not fit.
 These tests compile the main path's kernels at the widths the side-256
-road grid serves (S=16 sources, forward plan M=36,904 rows of K=16), and
-the engine's SSD program on one chip and on a 2x2 data mesh.
+road grid serves (S=16 sources, forward plan M=36,904 rows of K=16; its
+core's plan M=69,320 rows for the sparse bellman round), and the
+engine's SSD program on one chip and on a 2x2 data mesh.
 
 The topology is described inside a module fixture, never at import: only
 the test worker that runs this file loads the TPU library.
@@ -17,11 +18,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro import shardlib as sl
-from repro.core import QueryEngine, grid_road_graph
+from repro.core import QueryEngine, grid_road_graph, query
 from repro.launch.serve import build_served_index
 
 # Side-256 road grid served with the serve CLI's build (chip_smoke.py).
-S, N_PAD, M_FWD, K = 16, 65537, 36904, 16
+S, N_PAD, M_FWD, M_CORE, K = 16, 65537, 36904, 69320, 16
 
 
 @pytest.fixture(scope="module")
@@ -70,6 +71,23 @@ def test_minplus_compiles_up_to_closure_limit(one_chip, c):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_sparse_core_round_compiles_at_side256_width(one_chip):
+    """Two sparse bellman rounds over the side-256 core's plan."""
+    plan = (_sds((1, M_CORE), jnp.int32, one_chip),
+            _sds((1, M_CORE, K), jnp.int32, one_chip),
+            _sds((1, M_CORE, K), jnp.float32, one_chip),
+            _sds((1, M_CORE, K), jnp.int32, one_chip),
+            _sds((1, M_CORE), jnp.bool_, one_chip),
+            _sds((1,), jnp.bool_, one_chip))
+
+    def rounds(plan, d):
+        step = QueryEngine._arc_round(plan)
+        return jax.lax.fori_loop(0, 2, lambda i, x: step(x), d)
+
+    jax.jit(rounds).lower(plan, _sds((S, N_PAD), jnp.float32,
+                                     one_chip)).compile()
+
+
 def _ssd_operands(eng, sharding):
     """The engine's SSD operands as shapes: plans, core matrix, sources."""
     plans = jax.tree.map(lambda a: _sds(a.shape, a.dtype, sharding),
@@ -103,7 +121,19 @@ def test_ssd_compiles_batch_sharded_on_2x2(topo, one_chip):
     """``serve --data-parallel``: the SSD batch sharded over a 4-chip
     ``data`` mesh compiles (the Pallas kernel runs per shard) and leaves
     each chip a quarter of the ``[S, n]`` answer."""
+    _check_batch_sharded(_engine(32), topo, one_chip)
+
+
+def test_sparse_ssd_compiles_batch_sharded_on_2x2(topo, one_chip,
+                                                  monkeypatch):
+    """The same with the bellman round over the core's arcs."""
+    monkeypatch.setattr(query, "SPARSE_CORE_RATIO", 0)
     eng = _engine(32)
+    assert eng.core_sparse
+    _check_batch_sharded(eng, topo, one_chip)
+
+
+def _check_batch_sharded(eng, topo, one_chip):
     # The program's answer alone, without its counts.
     state = jax.jit(lambda *operands: eng._ssd_jit(*operands)[0])
     single = state.lower(*_ssd_operands(eng, one_chip)).compile()
